@@ -55,17 +55,16 @@ def run(verbose: bool = True) -> list[str]:
     rows.append(csv_row("kernel/flash_attention_ref_b2h8s512", dt * 1e6,
                         f"gflops={attn_flops/dt/1e9:.1f}"))
 
-    # ssd chunk oracle (jamba hot-spot): B2 H16 L256 P64 N128
-    from repro.kernels.ssd_chunk.ref import ssd_chunk_ref
-    x2 = jnp.asarray(rng.normal(size=(256, 16, 64)).astype(np.float32))
-    a2 = jnp.asarray(rng.uniform(0.8, 1.0, size=(256, 16)).astype(np.float32))
-    b2 = jnp.asarray(rng.normal(size=(256, 128)).astype(np.float32))
-    c2 = jnp.asarray(rng.normal(size=(256, 128)).astype(np.float32))
-    h2 = jnp.zeros((16, 128, 64), jnp.float32)
-    f = jax.jit(ssd_chunk_ref)
-    dt = _time(f, x2, a2, b2, c2, h2)
+    # SSD chunked scan, one chunk (jamba hot-spot): H16 L256 P64 N128
+    from repro.models.ssm import _ssd_chunk_scan
+    x2 = jnp.asarray(rng.normal(size=(1, 256, 16, 64)).astype(np.float32))
+    a2 = jnp.asarray(rng.uniform(0.8, 1.0, size=(1, 256, 16)).astype(np.float32))
+    b2 = jnp.asarray(rng.normal(size=(1, 256, 128)).astype(np.float32))
+    c2 = jnp.asarray(rng.normal(size=(1, 256, 128)).astype(np.float32))
+    f = jax.jit(lambda x, a, b, c: _ssd_chunk_scan(x, a, b, c, chunk=256))
+    dt = _time(f, x2, a2, b2, c2)
     ssd_flops = 2 * 256 * 256 * (128 + 16 * 64)  # scores + weighted sum approx
-    rows.append(csv_row("kernel/ssd_chunk_ref_L256", dt * 1e6,
+    rows.append(csv_row("kernel/ssd_chunk_scan_L256", dt * 1e6,
                         f"gflops={ssd_flops/dt/1e9:.1f}"))
 
     # kernel-free landmark selection vs exact kernel selection (future-work impl)

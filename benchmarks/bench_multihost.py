@@ -16,6 +16,10 @@ The row tracked in ``BENCH_selection.json``:
 
 ``BENCH_FAST=1`` shrinks n/reps (CI smoke: the multihost-smoke job runs
 this module explicitly and greps for the row).
+
+CPU only, never on the chip path: the children are started after the
+parent has imported JAX, and a chip belongs to one process, so every child
+runs with ``JAX_PLATFORMS=cpu``.
 """
 from __future__ import annotations
 
@@ -30,7 +34,8 @@ from repro.testing.faults import launch_hosts
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: each child exposes ONE CPU device; the global mesh is 2 devices
+#: each child exposes ONE CPU device; the global mesh is 2 devices.  The
+#: children must stay on the CPU: the parent may already hold the chip.
 CHILD_ENV = {"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""}
 
 BENCH_SCRIPT = r"""

@@ -335,9 +335,10 @@ def run(verbose: bool = True) -> list[str]:
 
     # Pallas gram-free FL kernel smoke (interpret mode off-TPU): exercises the
     # fused-similarity kernel on every benchmark run, including CI
+    from repro.kernels import resolve_interpret
     from repro.kernels.fl_gains import ops as fl_ops
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret()
     zn = normalize_rows(_features(256, d=32))
     c = jnp.zeros((256,))
     dt = _timeit(lambda: jax.block_until_ready(

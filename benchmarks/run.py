@@ -112,6 +112,9 @@ def main(argv: list[str] | None = None) -> None:
         bench_tuning,
     )
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else argv
     fast = os.environ.get("BENCH_FAST") == "1"
     # third field: which tracked trajectory file the module's rows merge into

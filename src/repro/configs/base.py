@@ -42,7 +42,6 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_state_dim: int = 128
     ssm_chunk: int = 256
-    ssm_impl: str = "chunked"        # chunked (pure JAX) | pallas (TPU kernel)
 
     # encoder-decoder (audio) / cross-attention (vlm)
     encoder_layers: int = 0

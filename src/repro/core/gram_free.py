@@ -85,7 +85,7 @@ def _sim_matrix(z: jax.Array) -> jax.Array:
 def make_gram_free_facility_location(
     *,
     use_pallas: bool = False,
-    interpret: bool = False,
+    interpret: bool | None = None,
     block_i: int = 512,
     block_j: int = 512,
 ) -> SetFunction:

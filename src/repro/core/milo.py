@@ -152,10 +152,7 @@ class MiloPreprocessor:
         if name == "graph_cut":
             kwargs["lam"] = self.graph_cut_lambda
         if name == "facility_location":
-            kwargs.update(
-                use_pallas=self.use_pallas,
-                interpret=jax.default_backend() != "tpu",
-            )
+            kwargs["use_pallas"] = self.use_pallas
         return sharded_mod.make_sharded_gram_free(
             name, n_shards=mesh.shape[sharded_mod.AXIS], **kwargs
         )
@@ -174,12 +171,10 @@ class MiloPreprocessor:
             if name == "graph_cut":
                 return gram_free_mod.make_gram_free_graph_cut(self.graph_cut_lambda)
             if name == "facility_location":
-                # compiled kernel on TPU; interpret mode is the CPU
-                # validation path, not a production route
+                # the kernels compile on the TPU and are interpreted on the
+                # CPU (repro.kernels.resolve_interpret)
                 return gram_free_mod.make_gram_free_facility_location(
-                    use_pallas=self.use_pallas,
-                    interpret=jax.default_backend() != "tpu",
-                )
+                    use_pallas=self.use_pallas)
             return gram_free_mod.get_gram_free(name)
         if name == "graph_cut":
             return submodular.make_graph_cut(self.graph_cut_lambda)

@@ -62,7 +62,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.gram_free import (
@@ -215,7 +214,7 @@ def make_sharded_facility_location(
     n_shards: int,
     axis: str = AXIS,
     use_pallas: bool = False,
-    interpret: bool = False,
+    interpret: bool | None = None,
     block_i: int = 512,
     block_j: int = 512,
     compress: str | None = None,
@@ -432,12 +431,12 @@ def _compiled(kind: str, fn: SetFunction, mesh: Mesh, axis: str, n: int,
               *extra):
     """One jitted shard_map program per (engine, set fn, mesh, shapes).
 
-    ``check_rep=False``: every per-element carry is replicated by
+    ``check_vma=False``: every per-element carry is replicated by
     construction (identical replicated inputs, deterministic ops), but the
-    rep checker cannot prove it through fori_loop + psum.
+    varying-axes checker cannot prove it through fori_loop + psum.
     """
     specs = dict(mesh=mesh, in_specs=(P(axis, None), P(None)),
-                 out_specs=P(None), check_rep=False)
+                 out_specs=P(None), check_vma=False)
 
     if kind == "greedy":
         (k,) = extra
@@ -485,7 +484,7 @@ def _compiled(kind: str, fn: SetFunction, mesh: Mesh, axis: str, n: int,
 
     else:  # pragma: no cover
         raise ValueError(kind)
-    return jax.jit(shard_map(inner, **specs))
+    return jax.jit(jax.shard_map(inner, **specs))
 
 
 def _valid_or_all(n: int, valid: jax.Array | None) -> jax.Array:

@@ -115,12 +115,13 @@ def gram_matrix_blocked(
     block: int = 1024,
     kw: float = 0.1,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blocked Gram matrix for large m: streams (block x d) tiles.
 
     ``use_pallas=True`` routes each tile through the Pallas similarity kernel
-    (``repro.kernels.similarity``); on CPU this requires ``interpret=True``.
+    (``repro.kernels.similarity``): compiled on the TPU, interpreted on the
+    CPU unless ``interpret`` says otherwise (``repro.kernels.resolve_interpret``).
 
     ``dot``'s non-negativity shift and ``rbf``'s mean-distance bandwidth are
     data-dependent *global* statistics: they are computed once over all tiles

@@ -249,12 +249,12 @@ disparity_min = SetFunction(
 
 
 @functools.lru_cache(maxsize=64)
-def make_facility_location_pallas(*, interpret: bool = False,
+def make_facility_location_pallas(*, interpret: bool | None = None,
                                   block_i: int = 512, block_j: int = 512) -> SetFunction:
     """Facility location with the Pallas ``fl_gains`` kernel as the gain
     engine (the O(n²)-per-step hot loop of greedy selection; DESIGN.md §6).
 
-    TPU deployment path; ``interpret=True`` validates on CPU (slow — tests
+    TPU deployment path; on the CPU it runs interpreted (slow — tests
     use small n).  Semantics identical to ``facility_location``
     (tests/test_kernels.py proves greedy-trajectory equality).
     """

@@ -55,16 +55,6 @@ _HOST_RE = re.compile(r"^host_(\d+)\.json$")
 # initialization
 # ---------------------------------------------------------------------------
 
-def is_initialized() -> bool:
-    """Whether ``jax.distributed.initialize`` has run in this process."""
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
-
-
 def initialize(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -79,7 +69,7 @@ def initialize(
     implementation is selected first — cross-process collectives on CPU
     require it, and the flag is only writable before initialization.
     """
-    if is_initialized():
+    if jax.distributed.is_initialized():
         return False
     coordinator_address = coordinator_address or os.environ.get("MILO_COORDINATOR")
     if num_processes is None:
@@ -90,10 +80,7 @@ def initialize(
         process_id = int(env_i) if env_i else None
     if coordinator_address is None or num_processes is None or num_processes < 2:
         return False
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # non-CPU build without the option: harmless
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -196,7 +183,7 @@ class FileBarrier:
 def default_barrier(timeout: float = 120.0) -> RuntimeBarrier | None:
     """The barrier real multi-process runs coordinate on (None when this is
     a plain single-process run with no coordination service)."""
-    return RuntimeBarrier(timeout) if is_initialized() else None
+    return RuntimeBarrier(timeout) if jax.distributed.is_initialized() else None
 
 
 # ---------------------------------------------------------------------------

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _fl_gains_kernel(k_ref, c_ref, out_ref):
     i = pl.program_id(1)  # reduction (ground-set) axis — innermost
@@ -55,7 +57,7 @@ def fl_gains_pallas(
     *,
     block_i: int = 512,
     block_j: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Gains for all candidate columns of K given max-cache c.
 
@@ -77,7 +79,7 @@ def fl_gains_pallas(
         ],
         out_specs=pl.BlockSpec((1, bj), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, n_cand), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(K, c[:, None])
     return out[0]
 
@@ -138,7 +140,7 @@ def fl_gains_gram_free_delta_pallas(
     *,
     block_i: int = 512,
     block_j: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fused lazy-greedy gain correction: both relu terms of the delta share
     one on-the-fly similarity tile (see ``ref.fl_gains_gram_free_delta_ref``).
@@ -169,7 +171,7 @@ def fl_gains_gram_free_delta_pallas(
         ],
         out_specs=pl.BlockSpec((1, bj), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, n_cand), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z, zc, c_old[:, None], c_new[:, None])
     return out[0]
 
@@ -182,7 +184,7 @@ def fl_gains_gram_free_pallas(
     *,
     block_i: int = 512,
     block_j: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Gram-free gains for all candidate rows of ``zc`` given max-cache ``c``.
 
@@ -207,6 +209,6 @@ def fl_gains_gram_free_pallas(
         ],
         out_specs=pl.BlockSpec((1, bj), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, n_cand), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z, zc, c[:, None])
     return out[0]

@@ -23,7 +23,7 @@ def fl_gains(
     block_i: int = 512,
     block_j: int = 512,
     use_pallas: bool = True,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Facility-location marginal gains; auto-pads to the block grid.
 
@@ -52,7 +52,7 @@ def fl_gains_gram_free(
     block_i: int = 512,
     block_j: int = 512,
     use_pallas: bool = True,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Gram-free facility-location marginal gains; auto-pads to the block grid.
 
@@ -90,7 +90,7 @@ def fl_gains_gram_free_delta(
     block_i: int = 512,
     block_j: int = 512,
     use_pallas: bool = True,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Lazy-greedy gain correction over a touched-row subset; auto-pads.
 

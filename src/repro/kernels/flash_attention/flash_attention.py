@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 _NEG_INF = -1e30
 
 
@@ -75,7 +77,7 @@ def flash_attention_pallas(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
     causal_offset: int | None = None,  # real (sk - sq) when inputs are padded
     sk_valid: int | None = None,       # number of real (unpadded) keys
 ) -> jax.Array:
@@ -130,7 +132,7 @@ def flash_attention_pallas(
             _vmem((bq, 1)),   # l: running denominator
             _vmem((bq, d)),   # acc: unnormalized output
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr)
     return out.reshape(b, hq, sq, d)
 

@@ -1,5 +1,9 @@
 """Public dispatch for flash attention: pads seq to block grid and routes
-Pallas (TPU) / interpret (CPU validation) / reference."""
+Pallas (TPU) / interpret (CPU validation) / reference.
+
+The Pallas kernel is forward-only: it has no backward pass, and
+differentiating through it raises ``NotImplementedError`` naming the
+training route (``attention_impl="chunked"``)."""
 from __future__ import annotations
 
 import jax
@@ -18,7 +22,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     use_pallas: bool = True,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Causal GQA attention; pads ragged seq lengths (exact — padded keys are
     masked out by causality / get zero weight via -inf logits)."""
@@ -39,15 +43,29 @@ def flash_attention(
         # sliced off below.
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    out = flash_attention_pallas(
-        q,
-        k,
-        v,
-        causal=causal,
-        block_q=bq,
-        block_k=bk,
-        interpret=interpret,
-        causal_offset=sk - sq,  # mask geometry of the *real* shapes
-        sk_valid=sk,
-    )
-    return out[:, :, :sq, :]
+
+    @jax.custom_vjp
+    def forward_only(q, k, v):
+        return flash_attention_pallas(
+            q,
+            k,
+            v,
+            causal=causal,
+            block_q=bq,
+            block_k=bk,
+            interpret=interpret,
+            causal_offset=sk - sq,  # mask geometry of the *real* shapes
+            sk_valid=sk,
+        )
+
+    def fwd(q, k, v):
+        return forward_only(q, k, v), None
+
+    def bwd(_, g):
+        raise NotImplementedError(
+            "the Pallas flash attention kernel is forward-only (it has no "
+            "backward pass); train with attention_impl='chunked'"
+        )
+
+    forward_only.defvjp(fwd, bwd)
+    return forward_only(q, k, v)[:, :, :sq, :]

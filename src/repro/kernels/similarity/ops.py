@@ -25,7 +25,7 @@ def similarity(
     block_q: int = 256,
     block_k: int = 256,
     use_pallas: bool = True,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Rescaled cosine Gram matrix; auto-pads ragged shapes to block grid."""
     if not use_pallas:

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _sim_kernel(zq_ref, zk_ref, out_ref, *, normalized: bool):
     zq = zq_ref[...].astype(jnp.float32)  # (bq, d)
@@ -43,7 +45,7 @@ def similarity_pallas(
     block_q: int = 256,
     block_k: int = 256,
     normalized: bool = False,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blocked Gram matrix via pallas_call. Shapes must divide the blocks."""
     mq, d = zq.shape
@@ -62,5 +64,5 @@ def similarity_pallas(
         ],
         out_specs=pl.BlockSpec((bq, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mq, mk), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(zq, zk)

@@ -66,7 +66,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: str | None
         with mesh:
             if shape.kind == "train":
                 step_fn = ts.make_train_step(
-                    cfg, opt, lambda s: 1e-4, interpret=True
+                    cfg, opt, lambda s: 1e-4
                 )
                 args = specs.input_specs(cfg, mesh, shape, opt)
                 lowered = jax.jit(step_fn).lower(*args)
@@ -85,7 +85,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: str | None
         from repro.launch import hlo_analysis
 
         mem = compiled.memory_analysis()
-        cost = hlo_analysis.xla_cost(compiled)
+        cost = compiled.cost_analysis()
         hlo_text = compiled.as_text()
         totals = hlo_analysis.analyze(hlo_text)
         rec.update(

@@ -42,15 +42,6 @@ _FREE_OPS = {
 }
 
 
-def xla_cost(compiled: Any) -> dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a one-element list of dicts, newer ones the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _shape_dims(shape_str: str) -> list[tuple[str, list[int]]]:
     out = []
     for m in _SHAPE_RE.finditer(shape_str):

@@ -5,29 +5,20 @@ never touches jax device state — ``dryrun.py`` must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* first jax
 init, and smoke tests must keep seeing 1 device.
 
-``make_mesh`` papers over the jax API drift around explicit axis types:
-``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types`` kwarg)
-only exist on newer jax; older versions get the positional call, which
-defaults every axis to Auto anyway.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-compatible ``jax.make_mesh`` with all axes typed Auto."""
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes), axis_types=(axis_type,) * len(axes)
-            )
-        except TypeError:  # jax exposes AxisType but not the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis typed Auto (GSPMD propagation)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,8 +3,9 @@
   * ``naive``   — materialized scores; smoke tests and short sequences.
   * ``chunked`` — pure-JAX flash (lax.scan over KV blocks, online softmax);
                   the dry-run path: O(S·block) memory, lowers on any backend.
-  * ``pallas``  — ``repro.kernels.flash_attention`` (TPU target; interpret=True
-                  for CPU validation).
+  * ``pallas``  — ``repro.kernels.flash_attention`` (TPU target, interpreted
+                  on the CPU).  Forward only: it has no backward pass, so
+                  it cannot train.
 
 Modes: ``train`` (full causal self-attn), ``prefill`` (train + returns KV to
 cache), ``decode`` (1 new token vs a fixed-size cache, in-place cache update).
@@ -126,11 +127,11 @@ def _chunked_attn(q, k, v, *, causal: bool, block: int = 512, k_len=None,
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d).astype(q.dtype)
 
 
-def _pallas_attn(q, k, v, *, causal: bool, interpret: bool) -> jax.Array:
+def _pallas_attn(q, k, v, *, causal: bool) -> jax.Array:
     from repro.kernels.flash_attention import ops as fa_ops
 
     out = fa_ops.flash_attention(
-        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), causal=causal, interpret=interpret
+        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), causal=causal
     )
     return out.swapaxes(1, 2)
 
@@ -147,7 +148,6 @@ def attention(
     kv_x: jax.Array | None = None,  # cross-attention keys/values source
     cache: KVCache | None = None,
     mode: str = "train",            # train | prefill | decode
-    interpret: bool = True,
 ) -> tuple[jax.Array, KVCache | None]:
     """Full attention sublayer: qkv proj -> rope -> attn -> out proj.
 
@@ -189,7 +189,7 @@ def attention(
     elif impl == "chunked":
         out = _chunked_attn(q, k, v, causal=causal, k_len=k_len)
     elif impl == "pallas":
-        out = _pallas_attn(q, k, v, causal=causal, interpret=interpret)
+        out = _pallas_attn(q, k, v, causal=causal)
     else:
         raise ValueError(f"unknown attention impl {impl!r}")
     # §Perf iter-6: pin the projection output to the storage dtype — XLA

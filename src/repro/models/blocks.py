@@ -94,7 +94,6 @@ def apply_block(
     context: jax.Array | None,
     cache: Cache,
     mode: str,
-    interpret: bool = True,
 ) -> tuple[jax.Array, Cache]:
     h = rms_norm(x, bparams["norm1"], cfg.norm_eps)
     new_cache: Cache = ()
@@ -110,15 +109,12 @@ def apply_block(
             kv_x=context if is_cross else None,
             cache=cache if (mixer == "attn" and mode == "decode") else None,
             mode=attn_mode,
-            interpret=interpret,
         )
         if mixer == "attn" and mode in ("prefill", "decode"):
             new_cache = kvc if mode == "decode" else _fit_cache(kvc, cache)
     elif mixer == "mamba":
         y, st = mamba(bparams["mixer"], h, chunk=cfg.ssm_chunk,
-                      state=cache if mode == "decode" else None, mode=mode,
-                      impl=cfg.ssm_impl if mode != "decode" else "chunked",
-                      interpret=interpret)
+                      state=cache if mode == "decode" else None, mode=mode)
         if mode in ("prefill", "decode"):
             new_cache = st
     elif mixer == "mlstm":

@@ -63,7 +63,7 @@ def init_lm(key: jax.Array, cfg: ModelConfig) -> dict:
 # layer-stack execution
 # --------------------------------------------------------------------------
 
-def _run_stack(params, cfg: ModelConfig, x, positions, context, caches, mode, interpret):
+def _run_stack(params, cfg: ModelConfig, x, positions, context, caches, mode):
     pattern = cfg.pattern
 
     def group_fn(x, gparams, gcaches):
@@ -73,7 +73,7 @@ def _run_stack(params, cfg: ModelConfig, x, positions, context, caches, mode, in
             x, nc = apply_block(
                 gparams[f"b{i}"], x, cfg=cfg, mixer=mixer, ffn=ffn,
                 positions=positions, context=context, cache=cache_i,
-                mode=mode, interpret=interpret,
+                mode=mode,
             )
             new_caches.append(nc)
         return x, tuple(new_caches)
@@ -97,7 +97,7 @@ def _run_stack(params, cfg: ModelConfig, x, positions, context, caches, mode, in
     return x, new_caches
 
 
-def _run_encoder(params, cfg: ModelConfig, frames, interpret):
+def _run_encoder(params, cfg: ModelConfig, frames):
     """Encoder over precomputed frame embeddings (conv frontend stub)."""
     enc = params["encoder"]
     pos = jnp.arange(frames.shape[1])[None, :]
@@ -105,7 +105,7 @@ def _run_encoder(params, cfg: ModelConfig, frames, interpret):
     def body(x, lp):
         x, _ = apply_block(
             lp, x, cfg=cfg, mixer="attn_nc", ffn="dense", positions=pos,
-            context=None, cache=(), mode="train", interpret=interpret,
+            context=None, cache=(), mode="train",
         )
         return x, None
 
@@ -122,17 +122,16 @@ def forward(
     mode: str = "train",
     caches=None,
     pos0: jax.Array | int = 0,
-    interpret: bool = True,
 ) -> tuple[jax.Array, Any]:
     b, s = tokens.shape
     x = constrain(embed(tokens, params["embed"]), "batch", None, None)
     if cfg.is_encdec:
         assert context is not None, "enc-dec model needs frame embeddings"
-        context = _run_encoder(params, cfg, context.astype(x.dtype), interpret)
+        context = _run_encoder(params, cfg, context.astype(x.dtype))
     p0 = jnp.asarray(pos0)
     p0 = p0[:, None] if p0.ndim == 1 else p0  # per-slot decode positions (B,)
     positions = p0 + jnp.arange(s)[None, :]
-    x, new_caches = _run_stack(params, cfg, x, positions, context, caches, mode, interpret)
+    x, new_caches = _run_stack(params, cfg, x, positions, context, caches, mode)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = constrain(unembed(x, params["embed"]), "batch", None, "model")
     return logits, new_caches
@@ -146,15 +145,13 @@ def loss_fn(
     params: dict,
     cfg: ModelConfig,
     batch: dict,
-    *,
-    interpret: bool = True,
 ) -> tuple[jax.Array, dict]:
     """Next-token cross entropy.  batch: tokens (B,S), labels (B,S),
     optional loss_mask (B,S), optional example weights w (B,) (MILO WRE),
     optional context (B,Nctx,D)."""
     logits, _ = forward(
         params, cfg, batch["tokens"], context=batch.get("context"),
-        mode="train", interpret=interpret,
+        mode="train",
     )
     labels = batch["labels"]
     # Vocab-sharding-friendly CE: the vocab axis of ``logits`` is sharded over
@@ -197,15 +194,15 @@ def init_caches(cfg: ModelConfig, batch: int, cache_len: int):
     return jax.tree.map(lambda a: jnp.broadcast_to(a, (cfg.n_groups,) + a.shape).copy(), g)
 
 
-def prefill(params, cfg: ModelConfig, tokens, caches, *, context=None, interpret=True):
+def prefill(params, cfg: ModelConfig, tokens, caches, *, context=None):
     return forward(params, cfg, tokens, context=context, mode="prefill",
-                   caches=caches, interpret=interpret)
+                   caches=caches)
 
 
-def decode_step(params, cfg: ModelConfig, token, caches, pos, *, context=None, interpret=True):
+def decode_step(params, cfg: ModelConfig, token, caches, pos, *, context=None):
     """One decode step.  token: (B, 1); pos: scalar int32 current position."""
     logits, caches = forward(
         params, cfg, token, context=context, mode="decode", caches=caches,
-        pos0=pos, interpret=interpret,
+        pos0=pos,
     )
     return logits, caches

@@ -98,8 +98,7 @@ def _ssd_chunk_scan(x, a, b, c, *, chunk: int, return_state: bool = False):
 
 
 def mamba(params: dict, x: jax.Array, *, chunk: int = 256,
-          state: jax.Array | None = None, mode: str = "train",
-          impl: str = "chunked", interpret: bool = True) -> tuple[jax.Array, jax.Array | None]:
+          state: jax.Array | None = None, mode: str = "train") -> tuple[jax.Array, jax.Array | None]:
     """Mamba/SSD mixer.  x: (B, S, D).
 
     ``mode='decode'``: S==1, sequential state update against ``state``
@@ -130,12 +129,6 @@ def mamba(params: dict, x: jax.Array, *, chunk: int = 256,
         )
         y = jnp.einsum("bn,bhnp->bhp", c_proj[:, 0], h_new)[:, None]       # (B,1,H,P)
         new_state = h_new
-    elif impl == "pallas":
-        from repro.kernels.ssd_chunk import ops as ssd_ops
-
-        y, h_fin = ssd_ops.ssd_scan(xh, a, b_proj, c_proj, chunk=chunk,
-                                    use_pallas=True, interpret=interpret)
-        new_state = h_fin if mode == "prefill" else None
     elif mode == "prefill":
         y, new_state = _ssd_chunk_scan(xh, a, b_proj, c_proj, chunk=chunk, return_state=True)
     else:

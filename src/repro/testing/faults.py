@@ -161,6 +161,11 @@ def launch_hosts(
     results in process order.  No return code policy is imposed here — a
     kill test asserts ``-SIGKILL`` on the victim and nonzero on the
     survivors, a happy-path test asserts all zero.
+
+    CPU only: the caller has usually imported JAX already, and a chip
+    belongs to one process, so children that reached for the chip would
+    fail or hang.  Pass ``JAX_PLATFORMS=cpu`` in ``env``; this harness stays
+    off the chip path.
     """
     import subprocess
     import sys

@@ -25,12 +25,12 @@ def init_train_state(key: jax.Array, cfg: ModelConfig, opt: Optimizer) -> TrainS
 
 
 def make_train_step(cfg: ModelConfig, opt: Optimizer, lr_schedule, *,
-                    grad_clip: float = 1.0, interpret: bool = True):
+                    grad_clip: float = 1.0):
     """Returns train_step(state, batch) -> (state, metrics)."""
 
     def train_step(state: TrainState, batch: dict):
         (loss, metrics), grads = jax.value_and_grad(
-            lambda p: lm.loss_fn(p, cfg, batch, interpret=interpret), has_aux=True
+            lambda p: lm.loss_fn(p, cfg, batch), has_aux=True
         )(state.params)
         if grad_clip:
             grads, gnorm = clip_by_global_norm(grads, grad_clip)
@@ -44,7 +44,7 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, lr_schedule, *,
 
 
 def make_grad_accum_train_step(cfg: ModelConfig, opt: Optimizer, lr_schedule, *,
-                               accum: int, grad_clip: float = 1.0, interpret: bool = True):
+                               accum: int, grad_clip: float = 1.0):
     """Gradient-accumulated step: batch dims are (accum, micro_batch, ...).
 
     Used by the elastic plan to preserve global batch on fewer devices.
@@ -55,7 +55,7 @@ def make_grad_accum_train_step(cfg: ModelConfig, opt: Optimizer, lr_schedule, *,
             grads, loss_sum = carry
             mb = jax.tree.map(lambda a: a[i], batch)
             (loss, _), g = jax.value_and_grad(
-                lambda p: lm.loss_fn(p, cfg, mb, interpret=interpret), has_aux=True
+                lambda p: lm.loss_fn(p, cfg, mb), has_aux=True
             )(state.params)
             return jax.tree.map(jnp.add, grads, g), loss_sum + loss
 
@@ -73,11 +73,11 @@ def make_grad_accum_train_step(cfg: ModelConfig, opt: Optimizer, lr_schedule, *,
     return train_step
 
 
-def make_prefill_step(cfg: ModelConfig, *, interpret: bool = True):
+def make_prefill_step(cfg: ModelConfig):
     def prefill_step(params, batch, caches):
         logits, caches = lm.prefill(
             params, cfg, batch["tokens"], caches,
-            context=batch.get("context"), interpret=interpret,
+            context=batch.get("context"),
         )
         # next-token for the last position of every request
         return jnp.argmax(logits[:, -1, :], axis=-1), caches
@@ -85,13 +85,13 @@ def make_prefill_step(cfg: ModelConfig, *, interpret: bool = True):
     return prefill_step
 
 
-def make_serve_step(cfg: ModelConfig, *, interpret: bool = True):
+def make_serve_step(cfg: ModelConfig):
     """decode: one new token against a KV cache of fixed length."""
 
     def serve_step(params, caches, batch):
         logits, caches = lm.decode_step(
             params, cfg, batch["token"], caches, batch["pos"],
-            context=batch.get("context"), interpret=interpret,
+            context=batch.get("context"),
         )
         return jnp.argmax(logits[:, -1, :], axis=-1), caches
 

@@ -2,9 +2,10 @@
 
 The load-bearing claims pinned here:
   * the flat (``by_class``, ``refine_factor=1``) path through the refactored
-    ``PartitionStrategy`` pipeline is BIT-identical to the pre-refactor
-    preprocessor — golden SHA-256 hashes of every artifact array AND the
-    config hash, for the gram and gram-free routes;
+    ``PartitionStrategy`` pipeline is BIT-identical to the class-wise loop it
+    replaced — every artifact array against that loop written out in the
+    test with the engines directly, and the config key-for-key, for the gram
+    and gram-free routes;
   * partition strategies produce disjoint covers with the documented
     block-size / label-purity / determinism properties;
   * ``proportional_budgets`` honors the min-1 floor (the [1,1,1,97] k=4
@@ -22,8 +23,6 @@ The load-bearing claims pinned here:
     preprocess after warmup records zero backend compiles.
 """
 from __future__ import annotations
-
-import hashlib
 
 import jax
 import numpy as np
@@ -55,10 +54,6 @@ def _golden_dataset():
     feats = rng.normal(size=(240, 16)).astype(np.float32)
     labels = rng.integers(0, 4, size=240).astype(np.int64)
     return feats, labels
-
-
-def _sha(a: np.ndarray) -> str:
-    return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()[:16]
 
 
 def _fl_value(feats: np.ndarray, idx: np.ndarray) -> float:
@@ -137,33 +132,88 @@ def test_make_partition_strategy_registry():
 # flat-path neutrality: the refactor must not move a single bit
 # ---------------------------------------------------------------------------
 
-_GOLDEN = {
-    # (gram_free) -> (sge, probs, importance, config_hash) pinned on the
-    # pre-refactor class-wise monolith; any drift in the default path is a
-    # regression even if selection quality looks unchanged
-    False: ("183e11afc7d59924", "462fb2939d3fb31f",
-            "5c3f1bd23d053f1a", "13532c3cc89b55af"),
-    True: ("183e11afc7d59924", "a312eeb4ce603ac4",
-           "4adf99770a3ef6fa", "010d8c24a018bbee"),
-}
+def _classwise_reference(feats, labels, *, gram_free, k_frac=0.1,
+                         n_subsets=4, key=None):
+    """The class-wise preprocessing loop the partition pipeline replaced,
+    written out with the engines directly: per class (in label order) one
+    key split, the pow2-bucketed SGE bank (graph cut) and WRE importance
+    (disparity min), a within-class Taylor-softmax weighted by class mass."""
+    import jax.numpy as jnp
+
+    from repro.core import gram_free as gf
+    from repro.core import submodular
+    from repro.core.exploration import taylor_softmax
+    from repro.core.greedy import greedy_importance, sge
+    from repro.core.partition import merge_class_selections
+    from repro.core.similarity import gram_matrix_blocked
+
+    m = len(labels)
+    k = max(1, int(round(k_frac * m)))
+    parts = partition_by_class(labels)
+    budgets = proportional_budgets(parts, k)
+    if gram_free:
+        easy = gf.make_gram_free_graph_cut(0.4)
+        hard = gf.get_gram_free("disparity_min")
+    else:
+        easy = submodular.make_graph_cut(0.4)
+        hard = submodular.get("disparity_min")
+    key = jax.random.PRNGKey(0) if key is None else key
+    banks, probs, imp = [], np.zeros(m, np.float32), np.zeros(m, np.float32)
+    for part, k_c in zip(parts, budgets):
+        key, k_sge = jax.random.split(key)
+        n_c = len(part.indices)
+        z = jnp.asarray(feats[part.indices])
+        A = (normalize_rows(z.astype(jnp.float32)) if gram_free
+             else gram_matrix_blocked(z, metric="cosine", block=2048))
+        n_pad = 1 << max(0, n_c - 1).bit_length()
+        k_run = min(n_pad, 1 << max(0, k_c - 1).bit_length())
+        pad = (0, n_pad - n_c)
+        A = jnp.pad(A, (pad, (0, 0)) if gram_free else (pad, pad))
+        valid = jnp.arange(n_pad) < n_c
+        subs = sge(easy, A, k_run, k_sge, n_subsets=n_subsets, eps=0.01,
+                   valid=valid)
+        banks.append(np.asarray(subs, np.int64)[:, :k_c])
+        imp_c = np.asarray(greedy_importance(hard, A, valid=valid),
+                           np.float32)[:n_c]
+        imp[part.indices] = imp_c
+        p_c = np.asarray(taylor_softmax(jnp.asarray(imp_c)), np.float32)
+        probs[part.indices] = p_c * (n_c / m)
+    bank = np.stack([merge_class_selections(parts, [b[i] for b in banks])
+                     for i in range(n_subsets)])
+    return bank, probs / probs.sum(), imp, budgets
+
+
+def _flat_config(gram_free: bool) -> dict:
+    """The flat path's artifact config, key for key (no partition keys)."""
+    return dict(
+        subset_fraction=0.1, k=24, n_sge_subsets=4, eps=0.01,
+        easy_fn="graph_cut", hard_fn="disparity_min", graph_cut_lambda=0.4,
+        classwise=True, metric="cosine", gram_free=gram_free,
+        bucket_classes=True, lazy_gains=False, lazy_threshold=0.125,
+        lazy_two_level=False, exact_sge_candidates=False,
+        shard_selection=False, encoder_id="precomputed", prep_seed=0,
+    )
 
 
 @pytest.mark.parametrize("gram_free", [False, True])
 def test_flat_path_bit_identical_to_pre_refactor_golden(gram_free):
+    from repro.core.metadata import config_hash
+
     feats, labels = _golden_dataset()
     pre = MiloPreprocessor(subset_fraction=0.1, n_sge_subsets=4,
                            gram_free=gram_free)
     md = pre.preprocess(feats, labels, jax.random.PRNGKey(0), prep_seed=0)
-    want_sge, want_probs, want_imp, want_cfg = _GOLDEN[gram_free]
-    assert _sha(np.asarray(md.sge_subsets, np.int64)) == want_sge
-    assert _sha(np.asarray(md.wre_probs, np.float32)) == want_probs
-    assert _sha(np.asarray(md.wre_importance, np.float32)) == want_imp
-    assert md.config_hash() == want_cfg
-    # legacy hash stability: the flat path stamps NO partition keys
-    for key in ("partition", "partition_block", "partition_seed",
-                "refine_factor"):
-        assert key not in md.config
-    assert list(md.class_budgets) == [6, 4, 8, 6]
+    bank, probs, imp, budgets = _classwise_reference(
+        feats, labels, gram_free=gram_free)
+    np.testing.assert_array_equal(np.asarray(md.sge_subsets, np.int64), bank)
+    np.testing.assert_array_equal(np.asarray(md.wre_importance, np.float32),
+                                  imp)
+    np.testing.assert_array_equal(np.asarray(md.wre_probs, np.float32), probs)
+    # legacy hash stability: the flat path stamps exactly the pre-hierarchy
+    # keys, so its config_hash (and every reuse check keyed on it) holds
+    assert md.config == _flat_config(gram_free)
+    assert md.config_hash() == config_hash(_flat_config(gram_free))
+    assert list(md.class_budgets) == budgets == [6, 4, 8, 6]
 
 
 # ---------------------------------------------------------------------------

@@ -162,3 +162,34 @@ def test_pallas_facility_location_setfunction_in_greedy():
     a = np.asarray(greedy(facility_location, K, 6).indices)
     b = np.asarray(greedy(fn_p, K, 6).indices)
     np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# interpret-mode decision and the forward-only attention kernel
+# ---------------------------------------------------------------------------
+
+def test_resolve_interpret_follows_backend(monkeypatch):
+    from repro.kernels import resolve_interpret
+
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    assert resolve_interpret(True) is True  # an explicit request still wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        resolve_interpret()
+
+
+def test_kernels_default_to_interpret_on_cpu():
+    """No interpret argument: the CPU interprets, so the kernel runs here
+    and matches its oracle (a compiled TPU call could not run on the CPU)."""
+    rng = np.random.default_rng(5)
+    z = jnp.asarray(rng.normal(size=(64, 32)).astype(np.float32))
+    zn = z / jnp.linalg.norm(z, axis=1, keepdims=True)
+    c = jnp.zeros((64,), jnp.float32)
+    np.testing.assert_allclose(fl_ops.fl_gains_gram_free(zn, zn, c),
+                               fl_gains_gram_free_ref(zn, zn, c),
+                               rtol=1e-5, atol=1e-4)

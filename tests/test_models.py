@@ -122,10 +122,26 @@ def test_attention_impls_agree():
     pos = jnp.arange(40)[None, :]
     outs = {}
     for impl in ("naive", "chunked", "pallas"):
-        y, _ = attention(p, x, pos, impl=impl, interpret=True)
+        y, _ = attention(p, x, pos, impl=impl)
         outs[impl] = np.asarray(y)
     np.testing.assert_allclose(outs["naive"], outs["chunked"], rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(outs["naive"], outs["pallas"], rtol=1e-4, atol=1e-4)
+
+
+def test_pallas_attention_cannot_train_and_says_so():
+    """attention_impl="pallas" runs forward; under jax.grad it raises an
+    error naming the kernel as forward-only, not a bare AssertionError."""
+    import dataclasses
+
+    cfg = dataclasses.replace(registry.smoke("internlm2-1.8b"),
+                              attention_impl="pallas")
+    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
+    batch = {"tokens": tok, "labels": tok}
+    loss, _ = lm.loss_fn(params, cfg, batch)
+    assert np.isfinite(float(loss))
+    with pytest.raises(NotImplementedError, match="forward-only"):
+        jax.grad(lambda p: lm.loss_fn(p, cfg, batch)[0])(params)
 
 
 def test_shape_applicability_matrix():

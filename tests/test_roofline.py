@@ -30,7 +30,7 @@ def test_xla_cost_analysis_undercounts_scans():
     # reports the same FLOPs for both — i.e. trip count is ignored.
     f4 = _compile(make(4), x, jax.ShapeDtypeStruct((4, 64, 64), jnp.float32))
     f8 = _compile(make(8), x, jax.ShapeDtypeStruct((8, 64, 64), jnp.float32))
-    assert ha.xla_cost(f4)["flops"] == ha.xla_cost(f8)["flops"]
+    assert f4.cost_analysis()["flops"] == f8.cost_analysis()["flops"]
 
 
 @pytest.mark.parametrize("n", [1, 4, 16])

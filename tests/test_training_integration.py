@@ -202,3 +202,35 @@ if st is not None:
         a = np.asarray(xs[:n], float)
         b = np.asarray(ys[:n], float)
         assert kendall_tau(a, b) == pytest.approx(_kendall_tau_loop(a, b))
+
+
+def test_train_lm_launcher_function_on_milo_subsets():
+    """``repro.launch.train.train_lm``: the launcher's body as a function of
+    (config, sequence length); the step is compiled before the run and the
+    per-step losses come back."""
+    from repro.launch.train import train_lm
+
+    cfg = registry.smoke("internlm2-1.8b")
+    out = train_lm(cfg, 32, epochs=2, subset_fraction=0.25, batch_size=4,
+                   n_docs=32, seed=0)
+    assert out["seq_len"] == 32 and out["subset_k"] == 8
+    assert out["steps"] == 4 and len(out["losses"]) == 4
+    assert all(np.isfinite(out["losses"]))
+    assert out["compile_s"] > 0 and out["step_s"] > 0
+
+
+def test_compile_cache_dir_env_wins_else_checkout(monkeypatch):
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
