@@ -254,15 +254,10 @@ class CraigPBSelector:
     grad_fn: Callable[[], np.ndarray]   # () -> (n, d) current per-sample grads
     k: int
     R: int = 10
-    selection_time: float = 0.0
 
     def indices_for_epoch(self, epoch: int) -> np.ndarray:
-        import time
-
         if epoch % self.R == 0 or not hasattr(self, "_idx"):
-            t0 = time.perf_counter()
             self._idx, self._weights = craig_pb_select(self.grad_fn(), self.k)
-            self.selection_time += time.perf_counter() - t0
         return self._idx
 
 
@@ -274,17 +269,12 @@ class GradMatchPBSelector:
     k: int
     R: int = 10
     lam: float = 0.5
-    selection_time: float = 0.0
 
     def indices_for_epoch(self, epoch: int) -> np.ndarray:
-        import time
-
         if epoch % self.R == 0 or not hasattr(self, "_idx"):
-            t0 = time.perf_counter()
             self._idx, self._weights = gradmatch_omp_select(
                 self.grad_fn(), self.k, self.lam
             )
-            self.selection_time += time.perf_counter() - t0
         return self._idx
 
 
@@ -297,15 +287,10 @@ class GlisterSelector:
     k: int
     R: int = 10
     eta: float = 0.1
-    selection_time: float = 0.0
 
     def indices_for_epoch(self, epoch: int) -> np.ndarray:
-        import time
-
         if epoch % self.R == 0 or not hasattr(self, "_idx"):
-            t0 = time.perf_counter()
             self._idx = glister_select(
                 self.grad_fn(), self.val_grad_fn(), self.k, self.eta
             )
-            self.selection_time += time.perf_counter() - t0
         return self._idx

@@ -25,6 +25,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.greedy import (
     greedy,
@@ -195,47 +196,50 @@ class MiloPreprocessor:
     ) -> tuple[np.ndarray, np.ndarray]:
         """SGE bank + WRE importance for one class partition.
 
-        ``feats_c`` is the class's (n_c, d) feature slice; returns the
-        ``(n_sge_subsets, k_c)`` local-index bank and the (n_c,) importance
-        vector.  ``warmup`` replays this exact path on dummy features, so
-        every engine/transform program it compiles is the one preprocess
-        will hit.
+        ``feats_c`` is the class's (n_c, d) feature slice, on the host or
+        already on the device; returns the ``(n_sge_subsets, k_c)``
+        local-index bank and the (n_c,) importance vector.  ``warmup``
+        replays this exact path on dummy features, so every
+        engine/transform program it compiles is the one preprocess will
+        hit.  The ``milo.*`` spans time the host: the engine spans cover
+        the dispatch only, each ``milo.fetch`` the wait for a result.
         """
         n_c = len(feats_c)
         z = jnp.asarray(feats_c)
-        if self.gram_free:
-            # the "kernel" threaded through the greedy engines is the
-            # row-normalized feature matrix itself: O(n·d), no Gram
-            A = normalize_rows(z.astype(jnp.float32))
-        else:
-            A = gram_matrix_blocked(
-                z, metric=self.metric, block=self.gram_block,
-                use_pallas=self.use_pallas,
-            )
-        valid = None
-        k_run = k_c
-        n_run = n_c
-        if bucket:
-            # Pad the problem (ground set AND budget) to the next
-            # power of two: the jit cache then keys on O(log²)
-            # distinct (bucket, k_run) pairs instead of every class
-            # size.  Masking is exact — padded elements start
-            # pre-selected and padded rows contribute nothing (zero
-            # Gram rows / +inf FL cover) — so DETERMINISTIC runs
-            # (full greedy -> WRE importance) match the unpadded run
-            # bit-for-bit.  The STOCHASTIC SGE draws use the padded
-            # candidate geometry (s and the per-step key split come
-            # from n_pad/k_run), so for a fixed seed the bank differs
-            # from an unbucketed run — a different but equally valid
-            # stochastic-greedy sample (see ROADMAP perf follow-ups).
-            n_pad = _next_pow2(n_c)
-            k_run = min(n_pad, _next_pow2(k_c))
-            if n_pad > n_c:
-                pad = ((0, n_pad - n_c), (0, 0)) if self.gram_free else (
-                    (0, n_pad - n_c), (0, n_pad - n_c))
-                A = jnp.pad(A, pad)
-            valid = jnp.arange(n_pad) < n_c
-            n_run = n_pad
+        with TraceAnnotation("milo.gram"):
+            if self.gram_free:
+                # the "kernel" threaded through the greedy engines is the
+                # row-normalized feature matrix itself: O(n·d), no Gram
+                A = normalize_rows(z.astype(jnp.float32))
+            else:
+                A = gram_matrix_blocked(
+                    z, metric=self.metric, block=self.gram_block,
+                    use_pallas=self.use_pallas,
+                )
+            valid = None
+            k_run = k_c
+            n_run = n_c
+            if bucket:
+                # Pad the problem (ground set AND budget) to the next
+                # power of two: the jit cache then keys on O(log²)
+                # distinct (bucket, k_run) pairs instead of every class
+                # size.  Masking is exact — padded elements start
+                # pre-selected and padded rows contribute nothing (zero
+                # Gram rows / +inf FL cover) — so DETERMINISTIC runs
+                # (full greedy -> WRE importance) match the unpadded run
+                # bit-for-bit.  The STOCHASTIC SGE draws use the padded
+                # candidate geometry (s and the per-step key split come
+                # from n_pad/k_run), so for a fixed seed the bank differs
+                # from an unbucketed run — a different but equally valid
+                # stochastic-greedy sample (see ROADMAP perf follow-ups).
+                n_pad = _next_pow2(n_c)
+                k_run = min(n_pad, _next_pow2(k_c))
+                if n_pad > n_c:
+                    pad = ((0, n_pad - n_c), (0, 0)) if self.gram_free else (
+                        (0, n_pad - n_c), (0, n_pad - n_c))
+                    A = jnp.pad(A, pad)
+                valid = jnp.arange(n_pad) < n_c
+                n_run = n_pad
         # exact_sge_candidates: derive the stochastic-greedy draw
         # size from the class's true geometry instead of the padded
         # bucket's (identical when unbucketed)
@@ -249,34 +253,38 @@ class MiloPreprocessor:
         from repro.core import sharded as sharded_mod
 
         shard_ok = mesh is not None and n_run % mesh.size == 0
-        if shard_ok:
-            subs = sharded_mod.sharded_sge(
-                easy_sh, A, k_run, k_sge, n_subsets=self.n_sge_subsets,
-                eps=self.eps, s=s_sge, mesh=mesh, valid=valid,
-            )
-        else:
-            subs = run_sge(
-                easy, A, k_run, k_sge, n_subsets=self.n_sge_subsets,
-                eps=self.eps, vmapped=self.sge_vmapped, valid=valid,
-                s=s_sge,
-            )
-        if shard_ok:
-            # lazy + sharded compose: the mesh classes run the same
-            # cached-gain engine inside shard_map instead of silently
-            # falling back to eager ring gains
-            imp_full = sharded_mod.sharded_greedy_importance(
-                hard_sh, A, mesh=mesh, valid=valid,
-                lazy_budget=self._lazy_budget(n_run, hard_sh),
-                lazy_two_level=self.lazy_two_level,
-            )
-        else:
-            imp_full = greedy_importance(
-                hard, A, valid=valid,
-                lazy_budget=self._lazy_budget(n_run, hard),
-                lazy_two_level=self.lazy_two_level,
-            )
-        subs_c = np.asarray(subs, np.int64)[:, :k_c]
-        imp = np.asarray(imp_full, np.float32)[:n_c]
+        with TraceAnnotation("milo.sge"):
+            if shard_ok:
+                subs = sharded_mod.sharded_sge(
+                    easy_sh, A, k_run, k_sge, n_subsets=self.n_sge_subsets,
+                    eps=self.eps, s=s_sge, mesh=mesh, valid=valid,
+                )
+            else:
+                subs = run_sge(
+                    easy, A, k_run, k_sge, n_subsets=self.n_sge_subsets,
+                    eps=self.eps, vmapped=self.sge_vmapped, valid=valid,
+                    s=s_sge,
+                )
+        with TraceAnnotation("milo.wre"):
+            if shard_ok:
+                # lazy + sharded compose: the mesh classes run the same
+                # cached-gain engine inside shard_map instead of silently
+                # falling back to eager ring gains
+                imp_full = sharded_mod.sharded_greedy_importance(
+                    hard_sh, A, mesh=mesh, valid=valid,
+                    lazy_budget=self._lazy_budget(n_run, hard_sh),
+                    lazy_two_level=self.lazy_two_level,
+                )
+            else:
+                imp_full = greedy_importance(
+                    hard, A, valid=valid,
+                    lazy_budget=self._lazy_budget(n_run, hard),
+                    lazy_two_level=self.lazy_two_level,
+                )
+        with TraceAnnotation("milo.fetch", bytes=subs.nbytes):
+            subs_c = np.asarray(subs, np.int64)[:, :k_c]
+        with TraceAnnotation("milo.fetch", bytes=imp_full.nbytes):
+            imp = np.asarray(imp_full, np.float32)[:n_c]
         return subs_c, imp
 
     def _refine_indices(
@@ -308,7 +316,8 @@ class MiloPreprocessor:
                 easy, A, k, lazy_budget=self._lazy_budget(n_u, easy),
                 two_level=self.lazy_two_level,
             )
-        return np.asarray(res.indices, np.int64)
+        with TraceAnnotation("milo.fetch", bytes=res.indices.nbytes):
+            return np.asarray(res.indices, np.int64)
 
     def _refine_bank(
         self,
@@ -442,42 +451,44 @@ class MiloPreprocessor:
         never appear in an SGE subset, and their indices are recorded in
         provenance.
         """
-        features = np.asarray(features)
-        report = None
-        if self.firewall is not None:
-            from repro.health.firewall import validate_features
+        with TraceAnnotation("milo.preprocess") as span:
+            features = np.asarray(features)
+            report = None
+            if self.firewall is not None:
+                from repro.health.firewall import validate_features
 
-            features, report = validate_features(
-                features, labels, policy=self.firewall,
-                subset_fraction=self.subset_fraction,
-                # overbudget detection mirrors the decomposition selection
-                # will actually use (classwise off -> single catch-all)
-                strategy=(self.partition_strategy() if self.classwise
-                          else None),
-            )
-        quarantined = report.quarantined_rows if report is not None else []
-        if quarantined:
-            m = features.shape[0]
-            labels_full = (
-                None if labels is None else np.asarray(labels, np.int64))
-            keep = np.setdiff1d(
-                np.arange(m, dtype=np.int64),
-                np.asarray(quarantined, np.int64),
-            )
-            md = self._preprocess_clean(
-                features[keep],
-                None if labels_full is None else labels_full[keep],
-                key, encoder_id=encoder_id, prep_seed=prep_seed,
-            )
-            md = self._lift_quarantined(md, keep, m, labels_full)
-        else:
-            md = self._preprocess_clean(
-                features, labels, key,
-                encoder_id=encoder_id, prep_seed=prep_seed,
-            )
-        if report is not None:
-            md.config["firewall"] = self.firewall
-            md.config["data_health"] = report.to_dict()
+                features, report = validate_features(
+                    features, labels, policy=self.firewall,
+                    subset_fraction=self.subset_fraction,
+                    # overbudget detection mirrors the decomposition selection
+                    # will actually use (classwise off -> single catch-all)
+                    strategy=(self.partition_strategy() if self.classwise
+                              else None),
+                )
+            quarantined = report.quarantined_rows if report is not None else []
+            if quarantined:
+                m = features.shape[0]
+                labels_full = (
+                    None if labels is None else np.asarray(labels, np.int64))
+                keep = np.setdiff1d(
+                    np.arange(m, dtype=np.int64),
+                    np.asarray(quarantined, np.int64),
+                )
+                md = self._preprocess_clean(
+                    features[keep],
+                    None if labels_full is None else labels_full[keep],
+                    key, encoder_id=encoder_id, prep_seed=prep_seed,
+                )
+                md = self._lift_quarantined(md, keep, m, labels_full)
+            else:
+                md = self._preprocess_clean(
+                    features, labels, key,
+                    encoder_id=encoder_id, prep_seed=prep_seed,
+                )
+            if report is not None:
+                md.config["firewall"] = self.firewall
+                md.config["data_health"] = report.to_dict()
+            span.set_metadata(partitions=len(md.class_budgets))
         return md
 
     @staticmethod
@@ -551,38 +562,49 @@ class MiloPreprocessor:
         wre_importance = np.zeros((m,), np.float32)
 
         for part, k_sel in zip(parts, sel_widths):
-            key, k_sge = jax.random.split(key)
             n_c = len(part.indices)
-            if k_sel <= 0:
-                per_class_sge.append(np.zeros((self.n_sge_subsets, 0), np.int64))
-                imp = np.zeros((n_c,), np.float32)
-            else:
-                subs_c, imp = self._class_selection(
-                    features[part.indices], k_sel, k_sge, bucket=bucket,
-                    mesh=mesh, easy=easy, hard=hard,
-                    easy_sh=easy_sh, hard_sh=hard_sh,
-                )
-                per_class_sge.append(subs_c)
-            wre_importance[part.indices] = imp
-            # Within-class Taylor-softmax, weighted by class mass so the global
-            # vector is a proper distribution with stratified expectation.
-            p_local = np.asarray(taylor_softmax(jnp.asarray(imp)), np.float32)
-            wre_probs[part.indices] = p_local * (n_c / m)
+            with TraceAnnotation("milo.partition", n_c=n_c, k_c=k_sel):
+                key, k_sge = jax.random.split(key)
+                if k_sel <= 0:
+                    per_class_sge.append(
+                        np.zeros((self.n_sge_subsets, 0), np.int64))
+                    imp = np.zeros((n_c,), np.float32)
+                else:
+                    with TraceAnnotation("milo.put",
+                                         bytes=n_c * features[0].nbytes):
+                        feats_c = jnp.asarray(features[part.indices])
+                    subs_c, imp = self._class_selection(
+                        feats_c, k_sel, k_sge, bucket=bucket,
+                        mesh=mesh, easy=easy, hard=hard,
+                        easy_sh=easy_sh, hard_sh=hard_sh,
+                    )
+                    per_class_sge.append(subs_c)
+                with TraceAnnotation("milo.softmax"):
+                    wre_importance[part.indices] = imp
+                    # Within-class Taylor-softmax, weighted by class mass so
+                    # the global vector is a proper distribution with
+                    # stratified expectation.
+                    p_dev = taylor_softmax(jnp.asarray(imp))
+                    with TraceAnnotation("milo.fetch", bytes=p_dev.nbytes):
+                        p_local = np.asarray(p_dev, np.float32)
+                    wre_probs[part.indices] = p_local * (n_c / m)
 
-        wre_probs = _normalize_probs(wre_probs)
-        if rf > 1:
-            # level-1: each slot's oversampled union refined down to k
-            sge_subsets = self._refine_bank(
-                features, parts, per_class_sge, k, mesh, easy, easy_sh
-            )
-        else:
-            sge_subsets = np.stack(
-                [
-                    merge_class_selections(parts, [s[i] for s in per_class_sge])
-                    for i in range(self.n_sge_subsets)
-                ],
-                axis=0,
-            )
+        with TraceAnnotation("milo.merge"):
+            wre_probs = _normalize_probs(wre_probs)
+            if rf > 1:
+                # level-1: each slot's oversampled union refined down to k
+                sge_subsets = self._refine_bank(
+                    features, parts, per_class_sge, k, mesh, easy, easy_sh
+                )
+            else:
+                sge_subsets = np.stack(
+                    [
+                        merge_class_selections(
+                            parts, [s[i] for s in per_class_sge])
+                        for i in range(self.n_sge_subsets)
+                    ],
+                    axis=0,
+                )
         config = dict(
             subset_fraction=self.subset_fraction,
             k=int(sge_subsets.shape[1]),

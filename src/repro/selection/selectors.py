@@ -27,7 +27,6 @@ phase tags, provenance, and uniform construction.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable
 
 import numpy as np
@@ -337,14 +336,13 @@ class SelfSupPrunePlanSelector(Selector):
 
 class _WindowedSelector(Selector):
     """Base for R-windowed model-dependent strategies: recompute the
-    (indices, weights) pair once per R-epoch window, tag plans ``adaptive``,
-    and accumulate ``selection_time`` — the cost MILO amortizes away."""
+    (indices, weights) pair once per R-epoch window and tag plans
+    ``adaptive``."""
 
     name = ""
 
     def __init__(self, R: int):
         self.R = R
-        self.selection_time = 0.0
         self._window: int | None = None
         self._idx: np.ndarray | None = None
         self._weights: np.ndarray | None = None
@@ -355,14 +353,11 @@ class _WindowedSelector(Selector):
     def plan(self, epoch: int) -> SelectionPlan:
         window = epoch // self.R
         if window != self._window or self._idx is None:
-            t0 = time.perf_counter()
             self._idx, self._weights = self._select()
-            self.selection_time += time.perf_counter() - t0
             self._window = window
         return SelectionPlan(
             self._idx, self._weights, "adaptive", epoch,
-            {"selector": self.name, "window": window,
-             "selection_time": self.selection_time},
+            {"selector": self.name, "window": window},
         )
 
     def reset_cache(self) -> None:

@@ -49,7 +49,8 @@ def _data_fingerprint(features: np.ndarray) -> str:
     """Cheap content identity for a feature matrix (same config + same
     length is not enough to prove an artifact belongs to this data)."""
     a = np.ascontiguousarray(np.asarray(features, np.float32))
-    return hashlib.sha256(a.tobytes()).hexdigest()[:16]
+    with jax.profiler.TraceAnnotation("milo.fingerprint", bytes=a.nbytes):
+        return hashlib.sha256(a.tobytes()).hexdigest()[:16]
 
 
 #: config keys that must match when reusing a saved preprocessing artifact
@@ -342,14 +343,17 @@ class MiloSession:
         precomputed one to skip rehashing the feature matrix).
         """
         cfg = self.config
-        md = cfg.preprocessor().preprocess(
-            features, labels, jax.random.PRNGKey(cfg.resolved_prep_seed()),
-            encoder_id=encoder_id, prep_seed=cfg.resolved_prep_seed(),
-        )
-        md.config["data_fingerprint"] = (
-            fingerprint if fingerprint is not None
-            else _data_fingerprint(features)
-        )
+        prep_seed = cfg.resolved_prep_seed()
+        with jax.profiler.TraceAnnotation("milo.build", m=len(features),
+                                          prep_seed=prep_seed):
+            md = cfg.preprocessor().preprocess(
+                features, labels, jax.random.PRNGKey(prep_seed),
+                encoder_id=encoder_id, prep_seed=prep_seed,
+            )
+            md.config["data_fingerprint"] = (
+                fingerprint if fingerprint is not None
+                else _data_fingerprint(features)
+            )
         return md
 
     def adopt_metadata(
