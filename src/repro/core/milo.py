@@ -20,6 +20,7 @@ protocol, and ``MiloSession`` drives preprocess/train/tune end to end.  The
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Callable, Sequence
 
 import jax
@@ -27,6 +28,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.core.buckets import (
+    BucketChunk,
+    bucket_chunks,
+    bucket_importance,
+    bucket_kernels,
+    bucket_sge,
+    chunk_byte_limit,
+    next_pow2,
+    sge_key_chain,
+)
 from repro.core.greedy import (
     greedy,
     greedy_importance,
@@ -47,10 +58,6 @@ from repro.core.partition import (
     proportional_budgets,
 )
 from repro.core.similarity import gram_matrix_blocked, normalize_rows
-
-
-def _next_pow2(n: int) -> int:
-    return 1 << max(0, int(n) - 1).bit_length()
 
 
 def _normalize_probs(p: np.ndarray) -> np.ndarray:
@@ -182,6 +189,17 @@ class MiloPreprocessor:
         return submodular.get(name)
 
     def _class_selection(
+        self, subs: np.ndarray, imp: np.ndarray, n_c: int, k_c: int
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """One partition's ``(n_sge_subsets, k_c)`` local-index bank and
+        ``(n_c,)`` importance vector, cut back from the engines' padded
+        outputs (``(n_sge_subsets, k_run)`` and ``(n_pad,)``) on either
+        route.  Kept as a method of its own because it is the one place
+        both routes hand back a partition's importances: the benchmark's
+        fault tests (``tests/bench/``) plant their faults here."""
+        return subs[:, :k_c].astype(np.int64), imp[:n_c]
+
+    def _partition_engines(
         self,
         feats_c: np.ndarray,
         k_c: int,
@@ -194,15 +212,16 @@ class MiloPreprocessor:
         easy_sh: submodular.SetFunction | None,
         hard_sh: submodular.SetFunction | None,
     ) -> tuple[np.ndarray, np.ndarray]:
-        """SGE bank + WRE importance for one class partition.
+        """SGE bank + WRE importance for one partition, the per-partition
+        route.
 
-        ``feats_c`` is the class's (n_c, d) feature slice, on the host or
-        already on the device; returns the ``(n_sge_subsets, k_c)``
-        local-index bank and the (n_c,) importance vector.  ``warmup``
-        replays this exact path on dummy features, so every
-        engine/transform program it compiles is the one preprocess will
-        hit.  The ``milo.*`` spans time the host: the engine spans cover
-        the dispatch only, each ``milo.fetch`` the wait for a result.
+        ``feats_c`` is the partition's (n_c, d) feature slice, on the host
+        or already on the device; returns the padded bank and importances
+        on the host (see ``_class_selection``).  ``warmup`` replays this
+        exact path on dummy features, so every engine/transform program it
+        compiles is the one preprocess will hit.  The ``milo.*`` spans time
+        the host: the engine spans cover the dispatch only, each
+        ``milo.fetch`` the wait for a result.
         """
         n_c = len(feats_c)
         z = jnp.asarray(feats_c)
@@ -232,8 +251,8 @@ class MiloPreprocessor:
                 # from n_pad/k_run), so for a fixed seed the bank differs
                 # from an unbucketed run — a different but equally valid
                 # stochastic-greedy sample (see ROADMAP perf follow-ups).
-                n_pad = _next_pow2(n_c)
-                k_run = min(n_pad, _next_pow2(k_c))
+                n_pad = next_pow2(n_c)
+                k_run = min(n_pad, next_pow2(k_c))
                 if n_pad > n_c:
                     pad = ((0, n_pad - n_c), (0, 0)) if self.gram_free else (
                         (0, n_pad - n_c), (0, n_pad - n_c))
@@ -282,10 +301,10 @@ class MiloPreprocessor:
                     lazy_two_level=self.lazy_two_level,
                 )
         with TraceAnnotation("milo.fetch", bytes=subs.nbytes):
-            subs_c = np.asarray(subs, np.int64)[:, :k_c]
+            subs = np.asarray(subs)
         with TraceAnnotation("milo.fetch", bytes=imp_full.nbytes):
-            imp = np.asarray(imp_full, np.float32)[:n_c]
-        return subs_c, imp
+            imp_full = np.asarray(imp_full, np.float32)
+        return subs, imp_full
 
     def _refine_indices(
         self, feats_u: np.ndarray, k: int, mesh, easy, easy_sh
@@ -372,6 +391,100 @@ class MiloPreprocessor:
             self._sharded_set_fn(self.hard_fn, sel_mesh),
         )
 
+    def _plan(
+        self,
+        geoms: Sequence[tuple[int, int]],
+        d: int,
+        *,
+        bucket: bool,
+        mesh,
+        hard: submodular.SetFunction,
+    ) -> tuple[list[BucketChunk], list[int]]:
+        """Route the partitions that have a budget: ``(chunks, loop)``.
+
+        ``geoms`` holds each partition's ``(n_c, k_sel)``.  The plain route
+        (bucketed, rescaled cosine, XLA set functions, eager gains, off the
+        ``sel`` mesh) runs as batched chunks (``core.buckets``); the rest
+        keep the per-partition loop, whose positions ``loop`` lists.  The
+        lazy engine stays off the batched route: under ``vmap`` its
+        ``lax.cond`` fallback becomes a select that pays the full recompute
+        on every step.  ``preprocess`` and ``warmup`` both route here, so
+        warmup compiles the chunk programs preprocess will run.
+        """
+        plain = bucket and self.metric == "cosine" and not self.use_pallas
+        batched, loop = [], []
+        for pos, (n_c, k_sel) in enumerate(geoms):
+            if k_sel <= 0:
+                continue
+            n_pad = next_pow2(n_c)
+            on_mesh = mesh is not None and n_pad % mesh.size == 0
+            if plain and not on_mesh and self._lazy_budget(n_pad, hard) is None:
+                batched.append((pos, n_c, k_sel))
+            else:
+                loop.append(pos)
+        chunks = bucket_chunks(
+            batched, d=d, gram_free=self.gram_free,
+            byte_limit=chunk_byte_limit(), eps=self.eps,
+            exact_s=self.exact_sge_candidates,
+        ) if batched else []
+        return chunks, loop
+
+    def _run_chunk(
+        self,
+        features: np.ndarray,
+        chunk: BucketChunk,
+        parts: Sequence[Partition],
+        keys: jax.Array,
+        easy: submodular.SetFunction,
+        hard: submodular.SetFunction,
+    ) -> tuple[np.ndarray, np.ndarray, list[tuple[range, np.ndarray]]]:
+        """One chunk on the device, read back in one ``milo.fetch``.
+
+        ``features`` is the host feature matrix and ``keys`` every
+        partition's SGE key.  Only the chunk's own rows go to the device,
+        so its footprint is bounded by its budget whatever the matrix's
+        size.  Returns the ``[P, n_subsets, k_run]`` banks, the ``[P,
+        n_pad]`` importances, and per true size ``n_c`` the chunk rows of
+        that size with their ``[rows, n_c]`` probabilities.
+        """
+        own = np.sort(np.concatenate([parts[i].indices
+                                      for i in chunk.members]))
+        rows = np.zeros((len(chunk.members), chunk.n_pad), np.int32)
+        for r, (i, n_c) in enumerate(zip(chunk.members, chunk.sizes)):
+            rows[r, :n_c] = np.searchsorted(own, parts[i].indices)
+        # a chunk that reads every row puts the matrix as it is, uncopied
+        src = features if len(own) == len(features) else features[own]
+        with TraceAnnotation("milo.put", bytes=src.size * 4):
+            x = jnp.asarray(src, jnp.float32)
+        with TraceAnnotation("milo.gram"):
+            kern, valid = bucket_kernels(x, rows, sizes=chunk.sizes,
+                                         gram_free=self.gram_free,
+                                         block=self.gram_block)
+        # the engines are the ones this module names, as on the
+        # per-partition route
+        with TraceAnnotation("milo.sge"):
+            banks = bucket_sge(
+                run_sge, easy, kern, valid, keys,
+                np.asarray(chunk.members, np.int32), k=chunk.k_run,
+                s=chunk.s, n_subsets=self.n_sge_subsets,
+                vmapped=self.sge_vmapped,
+            )
+        with TraceAnnotation("milo.wre"):
+            imp = bucket_importance(greedy_importance, hard, kern, valid)
+        with TraceAnnotation("milo.softmax"):
+            # the eager Taylor-softmax over the rows of one true size: it
+            # rounds each row as the per-partition route does, where a
+            # masked or fused softmax over the padded bucket does not
+            probs, lo = [], 0
+            for n_c, run in itertools.groupby(chunk.sizes):
+                hi = lo + len(list(run))
+                probs.append((range(lo, hi), taylor_softmax(imp[lo:hi, :n_c])))
+                lo = hi
+        out = [banks, imp] + [p for _, p in probs]
+        with TraceAnnotation("milo.fetch", bytes=sum(a.nbytes for a in out)):
+            banks, imp, *p_host = jax.device_get(out)
+        return banks, imp, [(sel, p) for (sel, _), p in zip(probs, p_host)]
+
     def warmup(
         self,
         buckets: Sequence[tuple[int, int]],
@@ -381,40 +494,56 @@ class MiloPreprocessor:
     ) -> int:
         """Pre-compile the engine programs for the given class geometries.
 
-        ``buckets`` holds the true per-class ``(n_c, k_c)`` shapes an
-        upcoming ``preprocess`` will see (e.g. ``[(5000, 500)] * 10`` for a
-        balanced 10-class dataset); ``d`` is the feature dimension (float32,
-        the dtype preprocess casts to).  Each distinct pair replays the full
-        per-class selection path — bucketing, masking, engine routing,
-        Taylor-softmax — on dummy features, so the jitted programs (keyed on
-        the factory-memoized set functions plus shapes) are compiled before
-        any real data arrives and the subsequent ``preprocess()`` triggers
-        zero backend compiles.  Returns the number of class geometries run;
-        outputs are discarded.
+        ``buckets`` holds every class's true ``(n_c, k_c)`` in the order an
+        upcoming ``preprocess`` will see them (e.g. ``[(5000, 500)] * 10``
+        for a balanced 10-class dataset); ``d`` is the feature dimension
+        (float32, the dtype preprocess casts to).  The classes are routed as
+        preprocess routes them (``_plan``) and each distinct batched chunk,
+        and each distinct per-partition geometry, replays its path —
+        bucketing, masking, engines, Taylor-softmax — on dummy features, so
+        the jitted programs are compiled before any real data arrives and
+        the subsequent ``preprocess()`` triggers zero backend compiles.
+        Returns the number of distinct programs run; outputs are discarded.
         """
         if key is None:
             key = jax.random.PRNGKey(0)
+        rf = max(1, int(self.refine_factor))
         bucket_list = [(int(n_c), int(k_c)) for n_c, k_c in buckets]
+        # the per-partition engines run at the oversampled bank width
+        geoms = [(n_c, min(n_c, rf * k_c)) for n_c, k_c in bucket_list]
         # mirror preprocess: bucketing only deduplicates across >1 partition
-        bucket = self.bucket_classes and len(bucket_list) > 1
+        bucket = self.bucket_classes and len(geoms) > 1
         easy = self._set_fn(self.easy_fn)
         hard = self._set_fn(self.hard_fn)
         mesh, easy_sh, hard_sh = self._selection_mesh()
+        chunks, loop = self._plan(geoms, d, bucket=bucket, mesh=mesh, hard=hard)
+        keys = sge_key_chain(key, len(geoms))
         rng = np.random.default_rng(0)
-        rf = max(1, int(self.refine_factor))
-        seen: set[tuple[int, int]] = set()
-        for n_c, k_c in bucket_list:
-            # the per-partition engines run at the oversampled bank width
-            k_sel = min(n_c, rf * k_c)
-            if k_sel <= 0 or (n_c, k_sel) in seen:
+        seen: set[tuple] = set()
+        if chunks:
+            # a stand-in ground set of the real shape, classes laid end to
+            # end: a chunk's programs take its members' rows
+            ends = np.cumsum([n_c for n_c, _ in geoms])
+            parts = [Partition(i, np.arange(end - n_c, end))
+                     for i, ((n_c, _), end) in enumerate(zip(geoms, ends))]
+            x = np.zeros((int(ends[-1]), d), np.float32)
+            for chunk in chunks:
+                sig = (chunk.n_pad, chunk.k_run, chunk.s, chunk.sizes)
+                if sig not in seen:
+                    seen.add(sig)
+                    self._run_chunk(x, chunk, parts, keys, easy, hard)
+        key_list = list(keys) if loop else []
+        for pos in loop:
+            n_c, k_sel = geoms[pos]
+            if (n_c, k_sel) in seen:
                 continue
             seen.add((n_c, k_sel))
-            key, k_sge = jax.random.split(key)
             dummy = rng.normal(size=(n_c, d)).astype(np.float32)
-            _, imp = self._class_selection(
-                dummy, k_sel, k_sge, bucket=bucket, mesh=mesh,
+            _, imp = self._partition_engines(
+                dummy, k_sel, key_list[pos], bucket=bucket, mesh=mesh,
                 easy=easy, hard=hard, easy_sh=easy_sh, hard_sh=hard_sh,
             )
+            imp = imp[:n_c]
             # preprocess follows every class selection with a within-class
             # Taylor-softmax on the (n_c,)-shaped importance — warm it too
             jax.block_until_ready(taylor_softmax(jnp.asarray(imp)))
@@ -478,12 +607,13 @@ class MiloPreprocessor:
                     features[keep],
                     None if labels_full is None else labels_full[keep],
                     key, encoder_id=encoder_id, prep_seed=prep_seed,
+                    span=span,
                 )
                 md = self._lift_quarantined(md, keep, m, labels_full)
             else:
                 md = self._preprocess_clean(
                     features, labels, key,
-                    encoder_id=encoder_id, prep_seed=prep_seed,
+                    encoder_id=encoder_id, prep_seed=prep_seed, span=span,
                 )
             if report is not None:
                 md.config["firewall"] = self.firewall
@@ -524,6 +654,7 @@ class MiloPreprocessor:
         *,
         encoder_id: str = "precomputed",
         prep_seed: int | None = None,
+        span: TraceAnnotation | None = None,
     ) -> MiloMetadata:
         features = np.asarray(features)
         if self.gram_free and self.metric != "cosine":
@@ -557,28 +688,51 @@ class MiloPreprocessor:
         bucket = self.bucket_classes and len(parts) > 1
         mesh, easy_sh, hard_sh = self._selection_mesh()
 
-        per_class_sge: list[np.ndarray] = []  # each (n_subsets, k_c) local idx
+        geoms = [(len(p.indices), w) for p, w in zip(parts, sel_widths)]
+        chunks, loop = self._plan(geoms, features.shape[1], bucket=bucket,
+                                  mesh=mesh, hard=hard)
+        # every partition's SGE key, the chain of splits a loop over the
+        # partitions would draw, in one call
+        keys = sge_key_chain(key, len(parts))
+        per_class_sge: list[np.ndarray] = [  # each (n_subsets, k_c) local idx
+            np.zeros((self.n_sge_subsets, 0), np.int64) for _ in parts]
         wre_probs = np.zeros((m,), np.float32)
         wre_importance = np.zeros((m,), np.float32)
+        for part, (n_c, k_sel) in zip(parts, geoms):
+            if k_sel <= 0 < n_c:
+                # no selection: zero importance, whose Taylor-softmax is
+                # uniform within the partition
+                p_local = np.full((n_c,), np.float32(1.0) / np.float32(n_c))
+                wre_probs[part.indices] = p_local * (n_c / m)
 
-        for part, k_sel in zip(parts, sel_widths):
+        for chunk in chunks:
+            with TraceAnnotation("milo.bucket", partitions=len(chunk.members),
+                                 n_pad=chunk.n_pad, k_run=chunk.k_run):
+                banks, imp, probs = self._run_chunk(features, chunk, parts,
+                                                    keys, easy, hard)
+                for r, (i, n_c) in enumerate(zip(chunk.members, chunk.sizes)):
+                    per_class_sge[i], wre_importance[parts[i].indices] = (
+                        self._class_selection(banks[r], imp[r], n_c,
+                                              sel_widths[i]))
+                for sel, p in probs:
+                    for j, r in enumerate(sel):
+                        idx = parts[chunk.members[r]].indices
+                        wre_probs[idx] = p[j] * (len(idx) / m)
+
+        key_list = list(keys) if loop else []
+        for pos in loop:
+            part, k_sel = parts[pos], sel_widths[pos]
             n_c = len(part.indices)
             with TraceAnnotation("milo.partition", n_c=n_c, k_c=k_sel):
-                key, k_sge = jax.random.split(key)
-                if k_sel <= 0:
-                    per_class_sge.append(
-                        np.zeros((self.n_sge_subsets, 0), np.int64))
-                    imp = np.zeros((n_c,), np.float32)
-                else:
-                    with TraceAnnotation("milo.put",
-                                         bytes=n_c * features[0].nbytes):
-                        feats_c = jnp.asarray(features[part.indices])
-                    subs_c, imp = self._class_selection(
-                        feats_c, k_sel, k_sge, bucket=bucket,
-                        mesh=mesh, easy=easy, hard=hard,
-                        easy_sh=easy_sh, hard_sh=hard_sh,
-                    )
-                    per_class_sge.append(subs_c)
+                with TraceAnnotation("milo.put",
+                                     bytes=n_c * features[0].nbytes):
+                    feats_c = jnp.asarray(features[part.indices])
+                subs, imp = self._class_selection(*self._partition_engines(
+                    feats_c, k_sel, key_list[pos], bucket=bucket,
+                    mesh=mesh, easy=easy, hard=hard,
+                    easy_sh=easy_sh, hard_sh=hard_sh,
+                ), n_c, k_sel)
+                per_class_sge[pos] = subs
                 with TraceAnnotation("milo.softmax"):
                     wre_importance[part.indices] = imp
                     # Within-class Taylor-softmax, weighted by class mass so
@@ -588,6 +742,9 @@ class MiloPreprocessor:
                     with TraceAnnotation("milo.fetch", bytes=p_dev.nbytes):
                         p_local = np.asarray(p_dev, np.float32)
                     wre_probs[part.indices] = p_local * (n_c / m)
+        if span is not None:
+            span.set_metadata(batched_partitions=sum(
+                len(c.members) for c in chunks))
 
         with TraceAnnotation("milo.merge"):
             wre_probs = _normalize_probs(wre_probs)

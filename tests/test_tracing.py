@@ -5,7 +5,9 @@ under ``jax.profiler`` on the CPU, inside a ``bench.window`` span as the
 benchmark opens it.  The ``.xplane.pb`` is read by the benchmark's own
 reader (``bench/spans.py``), which the CPU's trace serves for the host side
 only, and the spans are checked for their nesting, their counts and their
-byte arguments.
+byte arguments.  The four classes share one pow2 bucket, so they run as one
+batched chunk (``milo.bucket``); a lazy configuration shows the
+per-partition route's spans (``milo.partition``).
 """
 from __future__ import annotations
 
@@ -21,7 +23,9 @@ from repro.selection import MiloSession, MiloSessionConfig
 CLASSES, ROWS, WIDTH = 4, 64, 32
 PREP_SEED = 7
 LOOP = ("milo.build", "milo.preprocess")
+BUCKET = LOOP + ("milo.bucket",)
 PART = LOOP + ("milo.partition",)
+N_PAD = 64
 
 
 def _session() -> MiloSession:
@@ -35,14 +39,11 @@ def _data():
     return x, np.repeat(np.arange(CLASSES), ROWS)
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+def _trace(session, trace_dir):
     """The artifact built under the profiler, what ``spans.load`` reads of
     its trace, and each ``milo.*`` event's chain of enclosing span names."""
     x, y = _data()
-    session = _session()
     session.build_metadata(x, y)  # compile outside the trace
-    trace_dir = tmp_path_factory.mktemp("trace")
     jax.profiler.start_trace(str(trace_dir))
     try:
         with jax.profiler.TraceAnnotation("bench.window"):
@@ -52,6 +53,21 @@ def traced(tmp_path_factory):
     path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
     ops, bench_spans, events = spans.load(path)
     return md, ops, bench_spans, events, spans.chains(events)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    return _trace(_session(), tmp_path_factory.mktemp("trace"))
+
+
+@pytest.fixture(scope="module")
+def traced_loop(tmp_path_factory):
+    """The same build on the lazy gram-free route, which keeps the
+    per-partition loop."""
+    session = MiloSession(MiloSessionConfig(
+        subset_fraction=0.1, n_sge_subsets=2, prep_seed=PREP_SEED,
+        gram_free=True, lazy_gains=True, hard_fn="facility_location"))
+    return _trace(session, tmp_path_factory.mktemp("trace_loop"))
 
 
 def _with_chain(traced, chain):
@@ -75,30 +91,39 @@ def test_one_build_holds_the_fingerprint_and_the_preprocessor(traced):
     assert build[3] == {"m": CLASSES * ROWS, "prep_seed": PREP_SEED}
     (_fp,) = _with_chain(traced, ("milo.build", "milo.fingerprint"))
     (prep,) = _with_chain(traced, LOOP)
-    assert prep[3] == {"partitions": CLASSES}
+    # every class ran batched
+    assert prep[3] == {"partitions": CLASSES, "batched_partitions": CLASSES}
     assert all(c[0] == "milo.build" for c in chains)
 
 
+def _k_run(md) -> int:
+    return 1 << (int(max(md.class_budgets)) - 1).bit_length()
+
+
 def test_one_partition_span_per_class(traced):
+    """The classes share one bucket: one ``milo.bucket`` span holds them
+    all, and none of the per-partition route's ``milo.partition``."""
     md = traced[0]
-    parts = _with_chain(traced, PART)
-    assert len(parts) == CLASSES
-    assert [p[3]["k_c"] for p in parts] == [int(b) for b in md.class_budgets]
-    assert all(p[3]["n_c"] == ROWS for p in parts)
+    assert len({1 << (int(b) - 1).bit_length() for b in md.class_budgets}) == 1
+    (bucket,) = _with_chain(traced, BUCKET)
+    assert bucket[3] == {"partitions": CLASSES, "n_pad": N_PAD,
+                         "k_run": _k_run(md)}
+    assert _with_chain(traced, PART) == []
     assert len(_with_chain(traced, LOOP + ("milo.merge",))) == 1
 
 
 def test_three_fetches_nested_in_each_partition(traced):
+    """One blocking read per chunk, nested in its ``milo.bucket`` after
+    the put, the three dispatches and the Taylor-softmax."""
     chains = traced[4]
-    assert sum(c[-1] == "milo.fetch" for c in chains) == 3 * CLASSES
-    for part in _with_chain(traced, PART):
-        inner = _inside(traced, part)
-        fetches = [c for _, c in inner if c[-1] == "milo.fetch"]
-        assert sorted(fetches) == [PART + ("milo.fetch",)] * 2 + [
-            PART + ("milo.softmax", "milo.fetch")]
-        children = [c[-1] for _, c in inner if len(c) == len(PART) + 1]
-        assert children == ["milo.put", "milo.gram", "milo.sge", "milo.wre",
-                            "milo.fetch", "milo.fetch", "milo.softmax"]
+    assert sum(c[-1] == "milo.fetch" for c in chains) == 1
+    (bucket,) = _with_chain(traced, BUCKET)
+    inner = _inside(traced, bucket)
+    assert [c for _, c in inner if c[-1] == "milo.fetch"] == [
+        BUCKET + ("milo.fetch",)]
+    children = [c[-1] for _, c in inner if len(c) == len(BUCKET) + 1]
+    assert children == ["milo.put", "milo.gram", "milo.sge", "milo.wre",
+                        "milo.softmax", "milo.fetch"]
 
 
 def test_byte_arguments_are_the_arrays_nbytes(traced):
@@ -106,19 +131,35 @@ def test_byte_arguments_are_the_arrays_nbytes(traced):
     x, _ = _data()
     (fp,) = _with_chain(traced, ("milo.build", "milo.fingerprint"))
     assert fp[3]["bytes"] == x.nbytes
-    puts = _with_chain(traced, PART + ("milo.put",))
+    # the whole feature matrix goes to the device once
+    (put,) = _with_chain(traced, BUCKET + ("milo.put",))
+    assert put[3]["bytes"] == x.nbytes
+    # one read of the chunk's SGE banks (int32, padded to the bucket's
+    # budget), its importances (float32, padded to the bucket) and the
+    # classes' probabilities (float32)
+    (fetch,) = _with_chain(traced, BUCKET + ("milo.fetch",))
+    banks = CLASSES * md.config["n_sge_subsets"] * _k_run(md) * 4
+    assert fetch[3]["bytes"] == banks + CLASSES * N_PAD * 4 + x.shape[0] * 4
+
+
+def test_per_partition_route_keeps_its_spans(traced_loop):
+    """The lazy route: a ``milo.partition`` per class, each with its put,
+    dispatches and three reads, and no ``milo.bucket``."""
+    md = traced_loop[0]
+    x, _ = _data()
+    (prep,) = _with_chain(traced_loop, LOOP)
+    assert prep[3] == {"partitions": CLASSES, "batched_partitions": 0}
+    assert _with_chain(traced_loop, BUCKET) == []
+    parts = _with_chain(traced_loop, PART)
+    assert [p[3]["k_c"] for p in parts] == [int(b) for b in md.class_budgets]
+    assert all(p[3]["n_c"] == ROWS for p in parts)
+    for part in parts:
+        inner = _inside(traced_loop, part)
+        children = [c[-1] for _, c in inner if len(c) == len(PART) + 1]
+        assert children == ["milo.put", "milo.gram", "milo.sge", "milo.wre",
+                            "milo.fetch", "milo.fetch", "milo.softmax"]
+    puts = _with_chain(traced_loop, PART + ("milo.put",))
     assert [p[3]["bytes"] for p in puts] == [ROWS * x[0].nbytes] * CLASSES
-    for part in _with_chain(traced, PART):
-        sge, imp, probs = [ev for ev, c in _inside(traced, part)
-                           if c[-1] == "milo.fetch"]
-        n_c, k_c = part[3]["n_c"], part[3]["k_c"]
-        n_run = 1 << (n_c - 1).bit_length()
-        k_run = 1 << (k_c - 1).bit_length()
-        # the SGE bank (int32) and the importances (float32) padded to the
-        # class's pow2 bucket, then the class's probabilities (float32)
-        assert sge[3]["bytes"] == md.config["n_sge_subsets"] * k_run * 4
-        assert imp[3]["bytes"] == n_run * 4
-        assert probs[3]["bytes"] == n_c * 4
 
 
 def test_load_reads_the_spans_the_program_writes(traced):
@@ -129,8 +170,7 @@ def test_load_reads_the_spans_the_program_writes(traced):
     assert [n for _, _, n in bench_spans] == ["bench.window"]
     (lo, hi, _), = bench_spans
     assert spans.builds(events, lo, hi) == [
-        {"m": CLASSES * ROWS, "prep_seed": PREP_SEED,
-         "fetches": 3 * CLASSES}]
+        {"m": CLASSES * ROWS, "prep_seed": PREP_SEED, "fetches": 1}]
 
 
 def test_tracing_leaves_the_artifact_unchanged(traced):
