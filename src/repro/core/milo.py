@@ -452,8 +452,14 @@ class MiloPreprocessor:
         rows = np.zeros((len(chunk.members), chunk.n_pad), np.int32)
         for r, (i, n_c) in enumerate(zip(chunk.members, chunk.sizes)):
             rows[r, :n_c] = np.searchsorted(own, parts[i].indices)
-        # a chunk that reads every row puts the matrix as it is, uncopied
-        src = features if len(own) == len(features) else features[own]
+        # a chunk that reads every row puts the matrix as it is, uncopied;
+        # a chunk of a split group gathers its own rows on the host
+        if len(own) == len(features):
+            src = features
+        else:
+            with TraceAnnotation("milo.gather",
+                                 bytes=len(own) * features.shape[1] * 4):
+                src = features[own]
         with TraceAnnotation("milo.put", bytes=src.size * 4):
             x = jnp.asarray(src, jnp.float32)
         with TraceAnnotation("milo.gram"):
@@ -744,7 +750,7 @@ class MiloPreprocessor:
                     wre_probs[part.indices] = p_local * (n_c / m)
         if span is not None:
             span.set_metadata(batched_partitions=sum(
-                len(c.members) for c in chunks))
+                len(c.members) for c in chunks), chunks=len(chunks))
 
         with TraceAnnotation("milo.merge"):
             wre_probs = _normalize_probs(wre_probs)

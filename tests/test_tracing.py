@@ -6,8 +6,9 @@ benchmark opens it.  The ``.xplane.pb`` is read by the benchmark's own
 reader (``bench/spans.py``), which the CPU's trace serves for the host side
 only, and the spans are checked for their nesting, their counts and their
 byte arguments.  The four classes share one pow2 bucket, so they run as one
-batched chunk (``milo.bucket``); a lazy configuration shows the
-per-partition route's spans (``milo.partition``).
+batched chunk (``milo.bucket``); a smaller chunk budget splits them into two
+chunks, each gathering its own rows (``milo.gather``); a lazy configuration
+shows the per-partition route's spans (``milo.partition``).
 """
 from __future__ import annotations
 
@@ -17,7 +18,10 @@ import jax
 import numpy as np
 import pytest
 
-from bench import spans
+import repro.core.milo as milo
+from bench import spans, trace
+from bench.manifest import ROOT, Manifest
+from repro.core.buckets import chunk_bytes
 from repro.selection import MiloSession, MiloSessionConfig
 
 CLASSES, ROWS, WIDTH = 4, 64, 32
@@ -70,6 +74,16 @@ def traced_loop(tmp_path_factory):
     return _trace(session, tmp_path_factory.mktemp("trace_loop"))
 
 
+@pytest.fixture(scope="module")
+def traced_split(tmp_path_factory):
+    """The same build with a chunk budget of two partitions: two chunks,
+    each reading half the rows."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(milo, "chunk_byte_limit",
+                   lambda: 2 * chunk_bytes(N_PAD, WIDTH, False))
+        return _trace(_session(), tmp_path_factory.mktemp("trace_split"))
+
+
 def _with_chain(traced, chain):
     _, _, _, events, chains = traced
     return [ev for ev, c in zip(events, chains) if c == chain]
@@ -92,7 +106,8 @@ def test_one_build_holds_the_fingerprint_and_the_preprocessor(traced):
     (_fp,) = _with_chain(traced, ("milo.build", "milo.fingerprint"))
     (prep,) = _with_chain(traced, LOOP)
     # every class ran batched
-    assert prep[3] == {"partitions": CLASSES, "batched_partitions": CLASSES}
+    assert prep[3] == {"partitions": CLASSES, "batched_partitions": CLASSES,
+                       "chunks": 1}
     assert all(c[0] == "milo.build" for c in chains)
 
 
@@ -148,7 +163,8 @@ def test_per_partition_route_keeps_its_spans(traced_loop):
     md = traced_loop[0]
     x, _ = _data()
     (prep,) = _with_chain(traced_loop, LOOP)
-    assert prep[3] == {"partitions": CLASSES, "batched_partitions": 0}
+    assert prep[3] == {"partitions": CLASSES, "batched_partitions": 0,
+                       "chunks": 0}
     assert _with_chain(traced_loop, BUCKET) == []
     parts = _with_chain(traced_loop, PART)
     assert [p[3]["k_c"] for p in parts] == [int(b) for b in md.class_budgets]
@@ -180,3 +196,56 @@ def test_tracing_leaves_the_artifact_unchanged(traced):
     np.testing.assert_array_equal(plain.sge_subsets, md.sge_subsets)
     np.testing.assert_array_equal(plain.wre_probs, md.wre_probs)
     assert plain.config == md.config
+
+
+def test_a_split_group_gathers_once_per_chunk(traced_split):
+    """Each chunk of a split group gathers its own rows on the host, before
+    its put: ``bytes`` is the rows gathered times the width times 4."""
+    (prep,) = _with_chain(traced_split, LOOP)
+    assert prep[3] == {"partitions": CLASSES, "batched_partitions": CLASSES,
+                       "chunks": 2}
+    buckets = _with_chain(traced_split, BUCKET)
+    assert [b[3]["partitions"] for b in buckets] == [2, 2]
+    for bucket in buckets:
+        children = [c[-1] for _, c in _inside(traced_split, bucket)
+                    if len(c) == len(BUCKET) + 1]
+        assert children == ["milo.gather", "milo.put", "milo.gram",
+                            "milo.sge", "milo.wre", "milo.softmax",
+                            "milo.fetch"]
+    gathers = _with_chain(traced_split, BUCKET + ("milo.gather",))
+    assert [g[3]["bytes"] for g in gathers] == [2 * ROWS * WIDTH * 4] * 2
+    puts = _with_chain(traced_split, BUCKET + ("milo.put",))
+    assert [p[3]["bytes"] for p in puts] == [2 * ROWS * WIDTH * 4] * 2
+
+
+def test_no_gather_where_one_chunk_reads_every_row(traced, traced_loop):
+    for t in (traced, traced_loop):
+        assert all(c[-1] != "milo.gather" for c in t[4])
+
+
+def _gather_share(t, monkeypatch, tmp_path):
+    """``idle_in_gather.select`` on the CPU trace ``t``, its host events as
+    recorded and, since the CPU's trace has no device plane, one device
+    operation over the first tenth of the window."""
+    _, _, bench_spans, events, _ = t
+    (lo, hi, _), = bench_spans
+    named = {"/device:TPU:0": [(lo, lo + (hi - lo) // 10, "op")]}
+    ops = {p: [(a, b) for a, b, _ in evs] for p, evs in named.items()}
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(b"")
+    monkeypatch.setattr(spans, "trace_file", lambda: path)
+    monkeypatch.setattr(spans, "load", lambda p: (ops, bench_spans, events))
+    monkeypatch.setattr(spans, "_cache", {})
+    record = {"trace": trace.reduce(named, bench_spans)}
+    return Manifest(ROOT).metric_reader("idle_in_gather.select").read(record)
+
+
+def test_gather_reader_reads_a_share_of_the_window(traced_split, monkeypatch,
+                                                  tmp_path):
+    share = _gather_share(traced_split, monkeypatch, tmp_path)
+    assert share is not None and 0 < share <= 100
+
+
+def test_gather_reader_is_none_without_a_gather(traced, monkeypatch,
+                                               tmp_path):
+    assert _gather_share(traced, monkeypatch, tmp_path) is None
