@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, NamedTuple
 
 import jax
@@ -45,12 +46,53 @@ from repro.tuning.tuner import (
     subset_objective,
 )
 
+def _float32_rows(features: np.ndarray) -> np.ndarray:
+    """The matrix the fingerprint hashes: float32, C-ordered (a copy only
+    where the input is not already both)."""
+    return np.ascontiguousarray(np.asarray(features, np.float32))
+
+
+def _digest(a: np.ndarray) -> str:
+    """SHA-256 of a C-ordered array's buffer, read in place: the same
+    digest as of ``a.tobytes()``, without that copy."""
+    return hashlib.sha256(a).hexdigest()[:16]
+
+
 def _data_fingerprint(features: np.ndarray) -> str:
     """Cheap content identity for a feature matrix (same config + same
     length is not enough to prove an artifact belongs to this data)."""
-    a = np.ascontiguousarray(np.asarray(features, np.float32))
+    a = _float32_rows(features)
     with jax.profiler.TraceAnnotation("milo.fingerprint", bytes=a.nbytes):
-        return hashlib.sha256(a.tobytes()).hexdigest()[:16]
+        return _digest(a)
+
+
+def _timed_fingerprint(features: np.ndarray) -> tuple[str, int, int]:
+    """``_data_fingerprint`` without its span, for a worker thread: the
+    digest, the bytes hashed and the worker's nanoseconds (the conversion
+    to float32, where one is needed, and the hash)."""
+    t0 = time.perf_counter_ns()
+    a = _float32_rows(features)
+    return _digest(a), a.nbytes, time.perf_counter_ns() - t0
+
+
+def _run_while_fingerprinting(features: np.ndarray, work) -> tuple[Any, str]:
+    """``(work(), _data_fingerprint(features))``, the hash on a host thread
+    while ``work`` runs on this one.
+
+    ``hashlib`` releases the interpreter lock over a large buffer, so the
+    hash takes another core while ``work`` dispatches the device's programs
+    and waits on them.  ``milo.fingerprint`` opens on this thread around
+    the wait for the digest: its idle is the part of the hash that ``work``
+    did not hide, and ``hash_us`` is the worker's own time.  The worker
+    opens no ``milo.*`` span.  Leaving the executor joins the worker, so no
+    thread outlives the call, whichever side raises."""
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        pending = pool.submit(_timed_fingerprint, features)
+        out = work()
+        with jax.profiler.TraceAnnotation("milo.fingerprint") as span:
+            digest, nbytes, hash_ns = pending.result()
+            span.set_metadata(bytes=nbytes, hash_us=hash_ns // 1000)
+    return out, digest
 
 
 #: config keys that must match when reusing a saved preprocessing artifact
@@ -340,20 +382,25 @@ class MiloSession:
         its build function — the store owns persistence and caching, so the
         session must not also write files or mutate ``self.metadata`` here.
         The data fingerprint is always stamped (callers may pass a
-        precomputed one to skip rehashing the feature matrix).
+        precomputed one to skip rehashing the feature matrix); without one,
+        the matrix is hashed on a host thread while the pass runs.
         """
         cfg = self.config
         prep_seed = cfg.resolved_prep_seed()
-        with jax.profiler.TraceAnnotation("milo.build", m=len(features),
-                                          prep_seed=prep_seed):
-            md = cfg.preprocessor().preprocess(
+
+        def work():
+            return cfg.preprocessor().preprocess(
                 features, labels, jax.random.PRNGKey(prep_seed),
                 encoder_id=encoder_id, prep_seed=prep_seed,
             )
-            md.config["data_fingerprint"] = (
-                fingerprint if fingerprint is not None
-                else _data_fingerprint(features)
-            )
+
+        with jax.profiler.TraceAnnotation("milo.build", m=len(features),
+                                          prep_seed=prep_seed):
+            if fingerprint is None:
+                md, fingerprint = _run_while_fingerprinting(features, work)
+            else:
+                md = work()
+            md.config["data_fingerprint"] = fingerprint
         return md
 
     def adopt_metadata(

@@ -38,7 +38,7 @@ def array_fingerprint(arr: np.ndarray) -> str:
     h = hashlib.sha256()
     h.update(str(a.dtype).encode())
     h.update(str(a.shape).encode())
-    h.update(a.tobytes())
+    h.update(a)  # the buffer in place: the digest of a.tobytes(), uncopied
     return h.hexdigest()[:16]
 
 

@@ -157,6 +157,21 @@ def test_byte_arguments_are_the_arrays_nbytes(traced):
     assert fetch[3]["bytes"] == banks + CLASSES * N_PAD * 4 + x.shape[0] * 4
 
 
+@pytest.mark.parametrize("name", ["traced", "traced_loop", "traced_split"])
+def test_the_fingerprint_wait_stays_on_the_calling_thread(name, request):
+    """The hash runs on a worker thread, but ``milo.fingerprint`` (the
+    wait for it) nests in ``milo.build`` on the caller's thread, with the
+    worker's time as ``hash_us``; the worker opens no ``milo.*`` span."""
+    t = request.getfixturevalue(name)
+    x, _ = _data()
+    (build,) = _with_chain(t, ("milo.build",))
+    (fp,) = _with_chain(t, ("milo.build", "milo.fingerprint"))
+    assert fp[4] == build[4]
+    assert fp[3]["bytes"] == x.nbytes
+    assert isinstance(fp[3]["hash_us"], int) and fp[3]["hash_us"] >= 0
+    assert all(ev[4] == build[4] for ev in t[3])
+
+
 def test_per_partition_route_keeps_its_spans(traced_loop):
     """The lazy route: a ``milo.partition`` per class, each with its put,
     dispatches and three reads, and no ``milo.bucket``."""
