@@ -30,6 +30,42 @@ class MetadataMismatchError(ValueError):
     """Loaded artifact does not match the expected preprocessing config."""
 
 
+#: Settings an artifact built before they existed does not record: their
+#: absence is accepted.
+_LEGACY_KEYS = frozenset({
+    "gram_free", "bucket_classes", "lazy_gains", "exact_sge_candidates",
+    "lazy_threshold", "partition_block", "partition_seed",
+})
+#: Settings the stamp leaves out at their default: absence means this value,
+#: on either side.
+_UNSTAMPED = {"partition": "by_class", "refine_factor": 1, "firewall": None}
+
+
+def check_identity(
+    stored: dict[str, Any], expected: dict[str, Any], where: str
+) -> None:
+    """Refuse an artifact whose config disagrees with ``expected``, the
+    identity of the settings it is to be reused under
+    (``MiloPreprocessor.artifact_identity``).
+
+    Raises one ``MetadataMismatchError`` naming every key that differs, as
+    ``{key: (stored, expected)}``.  A stored ``prep_seed`` of ``None`` marks
+    a direct ``MiloPreprocessor`` build and is accepted."""
+    bad = {}
+    for key in dict.fromkeys([*expected, *_UNSTAMPED]):
+        if key not in stored and key in _LEGACY_KEYS:
+            continue
+        have = stored.get(key, _UNSTAMPED.get(key))
+        want = expected.get(key, _UNSTAMPED.get(key))
+        if key == "prep_seed" and have is None:
+            continue
+        if have != want:
+            bad[key] = (have, want)
+    if bad:
+        raise MetadataMismatchError(
+            f"{where}: config mismatch on {bad} (stored, expected)")
+
+
 def config_hash(config: dict[str, Any]) -> str:
     """Stable short hash of a preprocessing config (canonical-JSON sha256)."""
     blob = json.dumps(config, sort_keys=True, separators=(",", ":"), default=str)

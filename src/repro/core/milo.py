@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Callable, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +152,38 @@ class MiloPreprocessor:
             self.partition, block_size=self.partition_block,
             seed=self.partition_seed,
         )
+
+    def artifact_identity(self, prep_seed: int | None = None) -> dict[str, Any]:
+        """The stamped config keys that shape an artifact's bits, so must
+        match before it is reused; ``prep_seed`` is the integer the draw's
+        key was derived from.  Keys the stamp omits are omitted here too:
+        ``lazy_threshold`` unless ``lazy_gains`` is on, ``firewall`` unless
+        one is set, and the partition keys on the flat path (``by_class``,
+        ``refine_factor`` 1).  ``core.metadata.check_identity`` compares an
+        artifact against it."""
+        ident: dict[str, Any] = dict(
+            subset_fraction=self.subset_fraction,
+            n_sge_subsets=self.n_sge_subsets,
+            eps=self.eps,
+            easy_fn=self.easy_fn,
+            hard_fn=self.hard_fn,
+            graph_cut_lambda=self.graph_cut_lambda,
+            classwise=self.classwise,
+            metric=self.metric,
+            gram_free=self.gram_free,
+            bucket_classes=self.bucket_classes,
+            lazy_gains=self.lazy_gains,
+            exact_sge_candidates=self.exact_sge_candidates,
+        )
+        if self.lazy_gains:
+            ident["lazy_threshold"] = self.lazy_threshold
+        if self.firewall is not None:
+            ident["firewall"] = self.firewall
+        strategy, rf = self.partition_strategy(), max(1, int(self.refine_factor))
+        if strategy.name != "by_class" or rf > 1:
+            ident.update(strategy.config(), refine_factor=rf)
+        ident["prep_seed"] = prep_seed
+        return ident
 
     def _sharded_set_fn(self, name: str, mesh) -> submodular.SetFunction:
         from repro.core import sharded as sharded_mod
@@ -622,7 +654,6 @@ class MiloPreprocessor:
                     encoder_id=encoder_id, prep_seed=prep_seed, span=span,
                 )
             if report is not None:
-                md.config["firewall"] = self.firewall
                 md.config["data_health"] = report.to_dict()
             span.set_metadata(partitions=len(md.class_budgets))
         return md
@@ -768,40 +799,17 @@ class MiloPreprocessor:
                     ],
                     axis=0,
                 )
-        config = dict(
-            subset_fraction=self.subset_fraction,
-            k=int(sge_subsets.shape[1]),
-            n_sge_subsets=self.n_sge_subsets,
-            eps=self.eps,
-            easy_fn=self.easy_fn,
-            hard_fn=self.hard_fn,
-            graph_cut_lambda=self.graph_cut_lambda,
-            classwise=self.classwise,
-            metric=self.metric,
-            gram_free=self.gram_free,
-            bucket_classes=self.bucket_classes,
-            # trajectory-affecting engine knobs (checked on artifact
-            # reuse); shard_selection is recorded for provenance only —
-            # sharded and single-device runs select identically
-            lazy_gains=self.lazy_gains,
-            lazy_threshold=self.lazy_threshold,
-            # provenance only, like shard_selection: two-level gathers
-            # are bit-identical to single-level, so artifacts stay
-            # portable across the knob
-            lazy_two_level=self.lazy_two_level,
-            exact_sge_candidates=self.exact_sge_candidates,
-            shard_selection=self.shard_selection,
-            encoder_id=encoder_id,
-            prep_seed=prep_seed,
-        )
-        # Partition provenance is stamped only when the hierarchical path is
-        # active: flat (by_class, rf == 1) configs stay key-for-key identical
-        # to pre-hierarchy builds, so their config_hash — and every artifact
-        # reuse check keyed on it — is unchanged (the firewall keys set the
-        # same precedent).
-        if strategy.name != "by_class" or rf > 1:
-            config.update(strategy.config())
-            config["refine_factor"] = rf
+        # the identity plus keys kept for provenance only: lazy_threshold
+        # shapes the bits only under lazy gains, and sharded and two-level
+        # runs select identically, so artifacts stay portable across them
+        config = {
+            "k": int(sge_subsets.shape[1]),
+            "lazy_threshold": self.lazy_threshold,
+            "lazy_two_level": self.lazy_two_level,
+            "shard_selection": self.shard_selection,
+            "encoder_id": encoder_id,
+            **self.artifact_identity(prep_seed),
+        }
         return MiloMetadata(
             sge_subsets=sge_subsets,
             wre_probs=wre_probs,

@@ -30,7 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.metadata import MetadataMismatchError, MiloMetadata, is_preprocessed
+from repro.core.metadata import (
+    MetadataMismatchError,
+    MiloMetadata,
+    check_identity,
+    is_preprocessed,
+)
 from repro.distributed import multihost
 from repro.core.milo import MiloPreprocessor
 from repro.data import pipeline as pipeline_mod
@@ -95,13 +100,6 @@ def _run_while_fingerprinting(features: np.ndarray, work) -> tuple[Any, str]:
     return out, digest
 
 
-#: config keys that must match when reusing a saved preprocessing artifact
-_PREPROCESS_KEYS = (
-    "subset_fraction", "n_sge_subsets", "eps", "easy_fn", "hard_fn",
-    "graph_cut_lambda", "classwise", "metric",
-)
-
-
 @dataclasses.dataclass
 class MiloSessionConfig:
     """Everything the session needs, in one object."""
@@ -142,7 +140,7 @@ class MiloSessionConfig:
     exact_sge_candidates: bool = False
     # input firewall policy screening the ground set before preprocessing
     # (None = off): "raise" | "repair" | "quarantine" — see
-    # repro.health.firewall.  Recorded in artifact provenance (data_health).
+    # repro.health.firewall.  Stamped into the artifact and checked on reuse.
     firewall: str | None = None
     # hierarchical partition-then-refine selection (see core.partition /
     # MiloPreprocessor): level-0 decomposition strategy ("by_class" is the
@@ -233,9 +231,10 @@ class MiloSessionConfig:
     def resolved_prep_seed(self) -> int:
         return self.seed if self.prep_seed is None else self.prep_seed
 
-    def expected_artifact_config(self) -> dict[str, Any]:
-        """The stored-config keys a reusable artifact must agree on."""
-        return {k: getattr(self, k) for k in _PREPROCESS_KEYS}
+    def artifact_identity(self) -> dict[str, Any]:
+        """What a reusable artifact must agree on: the preprocessor's
+        identity under this session's resolved ``prep_seed``."""
+        return self.preprocessor().artifact_identity(self.resolved_prep_seed())
 
 
 @dataclasses.dataclass
@@ -409,73 +408,27 @@ class MiloSession:
         """Install an externally owned artifact (e.g. one the serving layer's
         store built or reloaded) as this session's preprocessing result, after
         the same config verification a ``metadata_path`` load applies."""
-        expected = self.config.expected_artifact_config()
-        bad = {
-            k: (md.config.get(k), v)
-            for k, v in expected.items()
-            if k in md.config and md.config.get(k) != v
-        }
-        if bad:
-            raise MetadataMismatchError(
-                f"adopted artifact: config mismatch on {bad} (stored, expected)"
-            )
-        stored_seed = md.config.get("prep_seed")
-        expected_seed = self.config.resolved_prep_seed()
-        if stored_seed is not None and stored_seed != expected_seed:
-            raise MetadataMismatchError(
-                "adopted artifact: config mismatch on "
-                f"{{'prep_seed': ({stored_seed}, {expected_seed})}} "
-                "(stored, expected)"
-            )
-        self._check_partition_config(md, "adopted artifact")
+        check_identity(md.config, self.config.artifact_identity(),
+                       "adopted artifact")
         self.metadata = md
         self.loaded_from_artifact = loaded
         return md
-
-    def _check_partition_config(self, md: MiloMetadata, where: str) -> None:
-        """Hierarchical provenance guard shared by artifact load and adopt.
-
-        Partition keys are stamped only when the hierarchical path is active
-        (see ``MiloPreprocessor._preprocess_clean``), so absence means the
-        flat path: legacy flat artifacts keep loading into flat sessions,
-        while any partition/refine disagreement — including a hierarchical
-        session reading a flat artifact, whose bank was built over a
-        different decomposition — refuses."""
-        cfg = self.config
-        stored_part = md.config.get("partition", "by_class")
-        stored_rf = int(md.config.get("refine_factor", 1))
-        want_rf = max(1, int(cfg.refine_factor))
-        bad: dict[str, tuple] = {}
-        if stored_part != cfg.partition:
-            bad["partition"] = (stored_part, cfg.partition)
-        if stored_rf != want_rf:
-            bad["refine_factor"] = (stored_rf, want_rf)
-        # block/seed are stamped only by the strategies that depend on them
-        for key, want in (("partition_block", cfg.partition_block),
-                          ("partition_seed", cfg.partition_seed)):
-            if key in md.config and int(md.config[key]) != int(want):
-                bad[key] = (md.config[key], want)
-        if bad:
-            raise MetadataMismatchError(
-                f"{where}: config mismatch on {bad} (stored, expected)"
-            )
 
     def _load_artifact(
         self,
         encoder_id: str | None = None,
         data_fingerprint: str | None = None,
     ) -> MiloMetadata:
-        """Load + verify the configured artifact.  The SGE bank is a
-        stochastic-greedy draw, so a *recorded* preprocessing seed must match
-        this session's; artifacts from other entry points (direct
-        ``MiloPreprocessor``, pre-header formats) record no seed and are
-        accepted on config alone.  When the caller knows which encoder
-        produced its features, the artifact's recorded encoder must agree —
-        subsets selected over one representation are meaningless for another."""
+        """Load the configured artifact and check it against this session's
+        ``artifact_identity`` (``core.metadata.check_identity``).  When the
+        caller knows which encoder produced its features, or their
+        fingerprint, the artifact's recorded values must agree too: subsets
+        selected over one representation or dataset are meaningless for
+        another."""
         cfg = self.config
-        md = MiloMetadata.load(
-            cfg.metadata_path, expected_config=cfg.expected_artifact_config()
-        )
+        md = MiloMetadata.load(cfg.metadata_path)
+        check_identity(md.config, cfg.artifact_identity(),
+                       str(cfg.metadata_path))
         stored_enc = md.config.get("encoder_id")
         if (encoder_id is not None and stored_enc is not None
                 and stored_enc != encoder_id):
@@ -492,62 +445,6 @@ class MiloSession:
                 "different data (feature fingerprint mismatch); pass "
                 "force=True to rebuild"
             )
-        # gram_free / bucket_classes / lazy_gains / exact_sge_candidates
-        # change which selection trajectories the artifact holds, so a
-        # recorded value must agree; artifacts from before these knobs
-        # existed record neither and are accepted on the base config alone
-        # (same tolerance as prep_seed below).  shard_selection is recorded
-        # but deliberately NOT checked: sharded runs select identically to
-        # single-device up to sub-ulp near-tie resolution (see core.sharded),
-        # an accepted tolerance so artifacts stay portable across meshes —
-        # including lazy+sharded runs, where the trajectory-affecting knobs
-        # (lazy_gains, lazy_threshold) ARE checked and the mesh still is not.
-        for knob in ("gram_free", "bucket_classes", "lazy_gains",
-                     "exact_sge_candidates"):
-            stored_knob = md.config.get(knob)
-            expected_knob = getattr(cfg, knob)
-            if stored_knob is not None and bool(stored_knob) != expected_knob:
-                raise MetadataMismatchError(
-                    f"{cfg.metadata_path}: config mismatch on "
-                    f"{{{knob!r}: ({stored_knob}, {expected_knob})}} "
-                    "(stored, expected)"
-                )
-        # with lazy gains active the recompute threshold shapes the drift
-        # cadence (and thus near-tie resolution), so it must agree too
-        stored_thr = md.config.get("lazy_threshold")
-        if (cfg.lazy_gains and bool(md.config.get("lazy_gains"))
-                and stored_thr is not None
-                and float(stored_thr) != cfg.lazy_threshold):
-            raise MetadataMismatchError(
-                f"{cfg.metadata_path}: config mismatch on "
-                f"{{'lazy_threshold': ({stored_thr}, {cfg.lazy_threshold})}} "
-                "(stored, expected)"
-            )
-        stored_seed = md.config.get("prep_seed")
-        expected_seed = cfg.resolved_prep_seed()
-        if stored_seed is not None and stored_seed != expected_seed:
-            raise MetadataMismatchError(
-                f"{cfg.metadata_path}: config mismatch on "
-                f"{{'prep_seed': ({stored_seed}, {expected_seed})}} "
-                "(stored, expected) — set MiloSessionConfig.prep_seed="
-                f"{stored_seed} to reuse this artifact with a different "
-                "training seed"
-            )
-        # repair/quarantine rewrite the effective ground set, so an artifact
-        # that RECORDS a firewall policy must agree with this session's;
-        # pre-firewall artifacts record none and are accepted on the base
-        # config (same legacy tolerance as the knobs above)
-        stored_fw = md.config.get("firewall")
-        if "firewall" in md.config and stored_fw != cfg.firewall:
-            raise MetadataMismatchError(
-                f"{cfg.metadata_path}: config mismatch on "
-                f"{{'firewall': ({stored_fw!r}, {cfg.firewall!r})}} "
-                "(stored, expected)"
-            )
-        # hierarchical decomposition provenance: the bank's indices are only
-        # meaningful for the partition geometry + refine factor they were
-        # selected under
-        self._check_partition_config(md, str(cfg.metadata_path))
         return md
 
     def _require_metadata(

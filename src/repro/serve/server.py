@@ -193,22 +193,12 @@ class ServeRequest:
 
 
 def artifact_request_config(cfg: MiloSessionConfig) -> dict[str, Any]:
-    """The config view an artifact is keyed and verified on: the base
-    reuse-guard keys plus every knob that changes the selection trajectories
-    the artifact holds.  Deliberately excludes mesh/runtime knobs
-    (``shard_selection``, ``gram_block``, ...) — artifacts are portable
-    across those, exactly as ``MiloSession._load_artifact`` tolerates."""
-    req = cfg.expected_artifact_config()
-    req.update(
-        gram_free=cfg.gram_free,
-        bucket_classes=cfg.bucket_classes,
-        lazy_gains=cfg.lazy_gains,
-        exact_sge_candidates=cfg.exact_sge_candidates,
-        prep_seed=cfg.resolved_prep_seed(),
-    )
-    if cfg.lazy_gains:
-        req["lazy_threshold"] = cfg.lazy_threshold
-    return req
+    """The config an artifact is keyed and verified on: the request's
+    ``MiloSessionConfig.artifact_identity``, the same one the session's
+    load and adopt check.  Mesh and runtime knobs (``shard_selection``,
+    ``gram_block``, ...) are not in it, so artifacts stay portable across
+    them."""
+    return cfg.artifact_identity()
 
 
 class MiloServer:
